@@ -89,7 +89,7 @@ def _bs_kernel_scaled(kidx_ref, kcnt_ref, a_ref, b_ref, s_ref, o_ref,
 
     @pl.when(s == max_nnz - 1)
     def _done():
-        o_ref[...] = (acc_ref[...] * s_ref[...][None, :]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "max_nnz",
@@ -133,8 +133,10 @@ def _block_sparse_matmul(a, b, kidx, kcnt, *, bm, bn, bk, max_nnz,
 def _block_sparse_matmul_scaled(a, b, scale, kidx, kcnt, *, bm, bn, bk,
                                 max_nnz, interpret, out_dtype):
     """Quantized twin of ``_block_sparse_matmul``: B is the int8 payload,
-    ``scale`` (N,) f32 rides its own (bn,)-blocked spec indexed by j and is
-    applied to the f32 accumulator at the final s step."""
+    ``scale`` (1, N) f32 rides its own (1, bn)-blocked spec indexed by j and
+    is applied to the f32 accumulator at the final s step.  The scale is
+    2-D because Mosaic refuses a 1-D block whose tiling differs from the
+    array's XLA layout."""
     m, k = a.shape
     _, n = b.shape
     tm, tn, tk = m // bm, n // bn, k // bk
@@ -147,7 +149,7 @@ def _block_sparse_matmul_scaled(a, b, scale, kidx, kcnt, *, bm, bn, bk,
         return (kidx_ref[i, j, jnp.minimum(s, kcnt_ref[i, j] - 1)], j)
 
     def s_map(i, j, s, kidx_ref, kcnt_ref):
-        return (j,)
+        return (0, j)
 
     def o_map(i, j, s, kidx_ref, kcnt_ref):
         return (i, j)
@@ -160,7 +162,7 @@ def _block_sparse_matmul_scaled(a, b, scale, kidx, kcnt, *, bm, bn, bk,
             in_specs=[
                 pl.BlockSpec((bm, bk), a_map),
                 pl.BlockSpec((bk, bn), b_map),
-                pl.BlockSpec((bn,), s_map),
+                pl.BlockSpec((1, bn), s_map),
             ],
             out_specs=pl.BlockSpec((bm, bn), o_map),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
@@ -194,8 +196,8 @@ def block_sparse_matmul(a: jax.Array, b: jax.Array, meta, *,
     if scale is not None:
         assert scale.shape == (n,), (scale.shape, n)
         return _block_sparse_matmul_scaled(
-            a, b, scale.astype(jnp.float32), meta.kidx, meta.kcnt,
-            bm=bm, bn=bn, bk=bk, max_nnz=meta.max_nnz,
+            a, b, scale.astype(jnp.float32).reshape(1, n), meta.kidx,
+            meta.kcnt, bm=bm, bn=bn, bk=bk, max_nnz=meta.max_nnz,
             interpret=interpret, out_dtype=out_dtype)
     return _block_sparse_matmul(
         a, b, meta.kidx, meta.kcnt, bm=bm, bn=bn, bk=bk,

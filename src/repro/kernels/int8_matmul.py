@@ -35,12 +35,14 @@ def _int8_kernel(a_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int):
 
     @pl.when(k == n_k - 1)
     def _done():
-        o_ref[...] = (acc_ref[...] * s_ref[...][None, :]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "out_dtype"))
 def _int8_matmul(a, q, scale, *, bm, bn, bk, interpret, out_dtype):
+    # scale (1, N): Mosaic refuses a 1-D block whose tiling differs from
+    # the array's XLA layout, so the per-channel scales ride as one row
     m, k = a.shape
     _, n = q.shape
     pm, pk, pn = (-m) % bm, (-k) % bk, (-n) % bn
@@ -49,7 +51,7 @@ def _int8_matmul(a, q, scale, *, bm, bn, bk, interpret, out_dtype):
     if pk or pn:
         q = jnp.pad(q, ((0, pk), (0, pn)))
     if pn:
-        scale = jnp.pad(scale, (0, pn))
+        scale = jnp.pad(scale, ((0, 0), (0, pn)))
     mp, kp = a.shape
     np_ = q.shape[1]
     tm, tn, tk = mp // bm, np_ // bn, kp // bk
@@ -60,7 +62,7 @@ def _int8_matmul(a, q, scale, *, bm, bn, bk, interpret, out_dtype):
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
@@ -94,6 +96,6 @@ def int8_matmul(a: jax.Array, qw, *, bm: int = 128, bn: int = 128,
     m, k = a.shape
     n = qw.q.shape[1]
     out_dtype = out_dtype or a.dtype
-    return _int8_matmul(a, qw.q, qw.scale,
+    return _int8_matmul(a, qw.q, qw.scale.reshape(1, n),
                         bm=min(bm, m), bn=min(bn, n), bk=min(bk, k),
                         interpret=interpret, out_dtype=out_dtype)

@@ -1,13 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable (e)).
 
 Proves the distribution config is coherent without real hardware: for every
 assigned (architecture × input-shape) cell, ``jax.jit(step).lower(...)
 .compile()`` must succeed on the single-pod (16, 16) mesh AND the two-pod
-(2, 16, 16) mesh (512 placeholder host devices — set above, before any jax
-import).  Per cell it records:
+(2, 16, 16) mesh (512 placeholder host devices, set in ``main`` before the
+first device is touched).  Per cell it records:
 
   * ``memory_analysis()``  — per-device bytes (proves the cell fits HBM),
   * ``cost_analysis()``    — per-device FLOPs / bytes accessed,
@@ -22,6 +19,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -145,6 +143,10 @@ def main() -> None:
     ap.add_argument("--out", default="artifacts/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+
+    # compile-only tool: placeholder CPU devices, never an accelerator
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
 
     if args.all:
         cells = [(a, s) for a in ARCH_IDS for s in SHAPES

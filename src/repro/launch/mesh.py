@@ -15,21 +15,31 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+               devices=None) -> Mesh:
+    # Auto axes: the sharding rules place arrays with
+    # ``with_sharding_constraint``, which Explicit axes (the make_mesh
+    # default) refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh(model: Optional[int] = None) -> Mesh:
-    """Small mesh over whatever devices exist (CPU tests)."""
-    n = len(jax.devices())
+def make_host_mesh(model: Optional[int] = None, devices=None) -> Mesh:
+    """(data, model) mesh over ``devices`` (default: every device)."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
     model = model or 1
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"), devices)
 
 
 def mesh_chips(mesh: Mesh) -> int:

@@ -25,15 +25,18 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from repro.configs.base import get_config, get_smoke_config
     from repro.models import model as model_lib
     from repro.serve.engine import ServeEngine
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rng = jax.random.PRNGKey(args.seed)
-    params = model_lib.init_params(cfg, rng, dtype=jnp.float32)
+    # bf16 weights and decode state: a full-width model fits one chip
+    params = model_lib.init_params(cfg, rng, dtype=jnp.bfloat16)
     engine = ServeEngine(cfg, params, n_slots=args.slots,
-                         max_seq=args.max_seq)
+                         max_seq=args.max_seq, dtype=jnp.bfloat16)
 
     rs = np.random.default_rng(args.seed)
     t0 = time.time()

@@ -36,6 +36,8 @@ def main() -> None:
                     help="TP degree over available devices")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from repro.configs.base import ShapeConfig, get_config, get_smoke_config
     from repro.data.pipeline import DataConfig, TokenPipeline
     from repro.launch.mesh import make_host_mesh

@@ -191,8 +191,6 @@ def _apply_moe_local(p: Params, cfg: ArchConfig, xt: jax.Array) -> jax.Array:
 
 def _apply_moe_ep(p: Params, cfg: ArchConfig, x: jax.Array, rules
                   ) -> jax.Array:
-    from jax.experimental.shard_map import shard_map
-
     mesh = rules.mesh
     m = cfg.moe
     ep_axis = rules.logical.get("expert") or "model"
@@ -242,13 +240,13 @@ def _apply_moe_ep(p: Params, cfg: ArchConfig, x: jax.Array, rules
             (f_sel // m.top_k).reshape(-1)].add(contrib.reshape(-1, d))
         return y.reshape(bl, sl, d).astype(xb.dtype)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, ep_axis, None), P(),
                   P(ep_axis, None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None)),
         out_specs=P(batch_axes, ep_axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     # shard_map specs address raw arrays: planned weights are unwrapped here
     # and the sparse dispatch (if any) re-derives metadata inside the body

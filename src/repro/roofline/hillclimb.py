@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """§Perf hillclimb driver: re-analyze a cell under knob overrides and diff
 its roofline terms against the recorded baseline.
 
@@ -15,6 +12,7 @@ before/after of every term — the numbers that go into EXPERIMENTS.md §Perf.
 import argparse
 import dataclasses
 import json
+import os
 
 
 def main() -> None:
@@ -30,6 +28,12 @@ def main() -> None:
                     help="MoE capacity-factor override")
     ap.add_argument("--out", default="artifacts/roofline")
     args = ap.parse_args()
+
+    # compile-only tool: placeholder CPU devices, never an accelerator
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
     from repro.configs.base import get_config
     from repro.configs.cells import cell_flags, cell_shape

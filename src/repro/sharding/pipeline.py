@@ -93,12 +93,11 @@ def pipeline_apply(layer_fn: Callable, stage_params, x: jax.Array, *,
         outputs = jax.lax.psum(outputs, axis_name)
         return outputs.reshape(b, *x_local.shape[1:])
 
-    from jax.experimental.shard_map import shard_map
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return smapped(stage_params, x)
 
 

@@ -44,7 +44,9 @@ def cosine_lr(cfg: AdamWConfig, step: jax.Array) -> jax.Array:
 
 
 def init_opt_state(params) -> OptState:
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    # zeros_like keeps each parameter's sharding: moments live where their
+    # parameter does
+    zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
     return OptState(step=jnp.zeros((), jnp.int32), mu=zeros,
                     nu=jax.tree.map(jnp.copy, zeros))
 

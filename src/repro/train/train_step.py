@@ -149,17 +149,14 @@ def build_dp_compressed_step(cfg: ArchConfig, shape: ShapeConfig,
         metrics["loss"] = loss
         return params, opt_state, err, metrics
 
-    from jax.sharding import PartitionSpec
-    from jax.experimental.shard_map import shard_map
-
     batch_spec = {k: (P(None, axes) if k == "mrope_positions"
                       else P(axes)) for k in
                   model_lib.input_specs(cfg, shape)}
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         device_step, mesh=mesh,
         in_specs=(P(), P(), P(), batch_spec),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped, donate_argnums=(0, 1, 2))

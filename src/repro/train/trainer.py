@@ -26,6 +26,7 @@ from repro.checkpoint import ckpt as ckpt_lib
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.data.pipeline import TokenPipeline, with_frontend_inputs
 from repro.models import model as model_lib
+from repro.sharding.partition import partition_params
 from repro.train.optimizer import AdamWConfig, init_opt_state
 from repro.train.train_step import build_train_step
 
@@ -95,6 +96,11 @@ class Trainer:
     def init_state(self):
         rng = jax.random.PRNGKey(self.tcfg.seed)
         self.params = model_lib.init_params(self.cfg, rng, dtype=self.dtype)
+        if self.rules is not None:
+            # lay the parameters out by the partition rules (FSDP + TP);
+            # left alone, the step keeps most of them whole on every device
+            self.params = jax.device_put(
+                self.params, partition_params(self.params, self.rules))
         self.opt_state = init_opt_state(self.params)
         self.step = 0
 

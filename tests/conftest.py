@@ -1,15 +1,10 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests run on 1 CPU device;
-multi-device behaviour is exercised in subprocesses (see helpers below).
+multi-device behaviour is exercised in subprocesses (see helpers below),
+which are pinned to the CPU backend so that no test child reaches for an
+accelerator.
 
-Also hosts a minimal ``hypothesis`` shim: the container does not ship the
-real package, so property-test modules import ``given / settings /
-strategies`` from here.  When hypothesis *is* installed it is re-exported
-unchanged; otherwise a deterministic seeded-numpy sampler with the same
-decorator surface runs each property ``max_examples`` times.
-
-And a per-test timeout shim in the same spirit: ``pytest-timeout`` cannot
-be pip-installed here, so a SIGALRM itimer around each test call phase
-turns a hung async drain into a failing test instead of a wedged lane.
+A per-test timeout shim that needs no ``pytest-timeout`` plugin: a SIGALRM
+itimer around each test call phase turns a hung async drain into a failing test instead of a wedged lane.
 Default 600 s, overridable per test with ``@pytest.mark.timeout(N)`` or
 globally via ``PYTEST_PER_TEST_TIMEOUT`` (0 disables).  POSIX main-thread
 only — elsewhere it degrades to a no-op, never a false failure.
@@ -19,7 +14,6 @@ import signal
 import subprocess
 import sys
 import threading
-import zlib
 
 import numpy as np
 import pytest
@@ -70,78 +64,20 @@ def rng():
     return np.random.default_rng(0)
 
 
-def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
-    """Run a python snippet in a subprocess with N forced host devices."""
+def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600,
+                     extra_env=None) -> str:
+    """Run a python snippet in a CPU-only subprocess with N forced host
+    devices (``extra_env`` adds variables to its otherwise fixed
+    environment)."""
     env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={n_devices}",
+           "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": "src", "PATH": os.environ.get(
                "PATH", "/usr/bin:/bin:/usr/local/bin"),
            "HOME": os.environ.get("HOME", "/root")}
+    env.update(extra_env or {})
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout,
                           cwd=REPO_ROOT)
     assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
     return proc.stdout
 
-
-# ---------------------------------------------------------------------------
-# hypothesis shim (@given / @settings / strategies)
-# ---------------------------------------------------------------------------
-
-try:                                      # real hypothesis wins when present
-    from hypothesis import given, settings, strategies    # noqa: F401
-except ImportError:
-
-    class _Strategy:
-        """A sampler ``rng -> value`` with hypothesis' map/flatmap surface."""
-
-        def __init__(self, draw):
-            self._draw = draw
-
-        def map(self, f):
-            return _Strategy(lambda rng: f(self._draw(rng)))
-
-        def flatmap(self, f):
-            return _Strategy(lambda rng: f(self._draw(rng))._draw(rng))
-
-    class _Strategies:
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(
-                lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-        @staticmethod
-        def floats(min_value, max_value):
-            return _Strategy(
-                lambda rng: float(rng.uniform(min_value, max_value)))
-
-        @staticmethod
-        def sampled_from(elements):
-            elements = list(elements)
-            return _Strategy(
-                lambda rng: elements[int(rng.integers(len(elements)))])
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: bool(rng.integers(2)))
-
-    strategies = _Strategies()
-
-    def settings(max_examples=20, **_ignored):
-        def deco(f):
-            f._shim_max_examples = max_examples
-            return f
-        return deco
-
-    def given(**strats):
-        def deco(f):
-            def wrapper():
-                n = getattr(wrapper, "_shim_max_examples", 20)
-                seed = zlib.crc32(f.__qualname__.encode())
-                rng = np.random.default_rng(seed)
-                for _ in range(n):
-                    f(**{k: s._draw(rng) for k, s in strats.items()})
-            wrapper.__name__ = f.__name__
-            wrapper.__doc__ = f.__doc__
-            wrapper._shim_max_examples = getattr(f, "_shim_max_examples", 20)
-            return wrapper
-        return deco

@@ -13,12 +13,12 @@ import dataclasses
 import functools
 from types import SimpleNamespace
 
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import given, settings, strategies as st
 from repro.configs.base import ArchConfig, SparsityConfig, get_smoke_config
 from repro.models import model as model_lib
 from repro.serve.engine import (AdaptiveAdmission, AdmissionPolicy,
@@ -113,7 +113,7 @@ def test_async_sampled_streams_match_sync():
 # traffic still emits the oracle's streams
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(seed=st.integers(0, 10_000))
 def test_async_staggered_arrivals_match_oracle(seed):
     cfg, params = _tiny()

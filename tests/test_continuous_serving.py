@@ -9,12 +9,12 @@ the chunked fused engine emits exactly the per-token oracle's streams.
 import dataclasses
 import functools
 
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import given, settings, strategies as st
 from repro.configs.base import ArchConfig, SparsityConfig, get_smoke_config
 from repro.models import model as model_lib
 from repro.serve.engine import (SamplingParams, ServeEngine,
@@ -325,7 +325,7 @@ def test_recalibrate_clears_mask_cache():
 # staggered-traffic equivalence (property)
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(seed=st.integers(0, 10_000))
 def test_staggered_arrivals_match_oracle(seed):
     """However requests arrive — random lengths, random budgets, random

@@ -1,11 +1,8 @@
-"""Energy/latency model invariants (the paper's own evaluation framework).
-
-Property tests use the hypothesis-compatible conftest shim when the real
-package is absent (seeded-numpy sampling, same decorator surface)."""
+"""Energy/latency model invariants (the paper's own evaluation framework),
+with hypothesis property tests."""
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import pytest
-
-from conftest import given, settings, strategies as st
 
 from repro.core.energy_model import (DENSE, EYERISS, FLEXNN, TPU, ConvLayer,
                                      Schedule, SparsityStats, evaluate,

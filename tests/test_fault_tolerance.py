@@ -23,12 +23,12 @@ speculative block's tokens into a successor).
 import dataclasses
 import functools
 
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import given, settings, strategies as st
 from repro.configs.base import ArchConfig, SparsityConfig
 from repro.core.sparsity import prune_stacked_magnitude
 from repro.kernels import ops
@@ -493,7 +493,7 @@ def _run_chaos(seed, cfg, params, *, exec_cfg=None, kinds=_TARGETED,
     return eng
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_chaos_dense_async(seed):
     cfg, params = _tiny()
@@ -501,7 +501,7 @@ def test_chaos_dense_async(seed):
 
 
 @pytest.mark.slow
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_chaos_planned_speculative(seed):
     cfg, params, ec = _planned()
@@ -511,7 +511,7 @@ def test_chaos_planned_speculative(seed):
 
 
 @pytest.mark.slow
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_chaos_two_sided_with_recalibration(seed):
     """Two-sided engines launder NaN through the activation bitmap

@@ -229,7 +229,8 @@ cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=
 p = M.init_moe(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model), jnp.float32)
 y_oracle = M.apply_moe_gshard(p, cfg, x)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = make_rules(mesh, kind='train', n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads)
 with use_rules(rules):
     y_ep = jax.jit(lambda p, x: M.apply_moe(p, cfg, x))(p, x)

@@ -18,7 +18,8 @@ def test_pipeline_matches_sequential():
 import numpy as np, jax, jax.numpy as jnp
 from repro.sharding.pipeline import pipeline_apply, split_stages
 
-mesh = jax.make_mesh((4, 2), ('pod', 'data'))
+mesh = jax.make_mesh((4, 2), ('pod', 'data'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 L, D, B = 8, 16, 12
 
 def layer_fn(lp, h):
@@ -49,7 +50,8 @@ def test_pipeline_single_stage_degenerates():
 import numpy as np, jax, jax.numpy as jnp
 from repro.sharding.pipeline import pipeline_apply, split_stages
 
-mesh = jax.make_mesh((1, 8), ('pod', 'data'))
+mesh = jax.make_mesh((1, 8), ('pod', 'data'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 L, D, B = 4, 8, 8
 def layer_fn(lp, h):
     return h + lp['w']

@@ -20,12 +20,11 @@ bytes *multiplicatively*.  Covered here:
 """
 import dataclasses
 
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-
-from conftest import given, settings, strategies as st
 
 from repro.configs.base import SparsityConfig, get_smoke_config
 from repro.core import sparsity as S

@@ -71,7 +71,8 @@ from repro.models import model as M
 from repro.sharding.partition import (batch_shardings, make_rules,
                                       partition_params, tree_paths)
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke_config('yi-9b')
 rules = make_rules(mesh, kind='train', n_heads=cfg.n_heads,
                    n_kv_heads=cfg.n_kv_heads)
@@ -148,7 +149,8 @@ from repro.train.train_step import build_dp_compressed_step
 cfg = get_smoke_config('stablelm-1.6b')
 shape = ShapeConfig(name='t', kind='train', seq_len=16, global_batch=8,
                     loss_chunk=16, attn_chunk=16, remat='none')
-mesh = jax.make_mesh((8,), ('data',))
+mesh = jax.make_mesh((8,), ('data',),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 step = build_dp_compressed_step(cfg, shape, AdamWConfig(), mesh,
                                 CompressConfig(mode='int8'))
 params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)

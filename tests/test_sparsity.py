@@ -1,10 +1,8 @@
-"""Property tests for the two-sided sparsity machinery (hypothesis-style;
-runs on the deterministic conftest shim when hypothesis is not installed)."""
+"""Property tests for the two-sided sparsity machinery (hypothesis)."""
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import jax.numpy as jnp
 import pytest
-
-from conftest import given, settings, strategies as st
 
 from repro.core import sparsity as S
 from repro.kernels.ref import block_sparse_matmul_ref

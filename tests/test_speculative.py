@@ -18,10 +18,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import pytest
-
-from conftest import given, settings, strategies as st
 
 from repro.configs.base import get_smoke_config, SparsityConfig
 from repro.core.sparsity import (compile_plan_tiers, compile_weight_plan,
@@ -60,7 +59,7 @@ _SETUP_CACHE = {}
 
 def _get_setup():
     """Module-cached (cfg, params, exec_cfg) — plain function rather than
-    a fixture so the hypothesis-shim ``@given`` tests can use it too."""
+    a fixture so the hypothesis ``@given`` tests can use it too."""
     if "v" not in _SETUP_CACHE:
         cfg = _sparse_cfg(d_ff=256)
         params = _pruned_params(cfg)
@@ -104,7 +103,7 @@ def test_tier_zero_is_bitwise_the_unpruned_plan(tier_setup):
         np.testing.assert_array_equal(t.wkcnt, e.wkcnt)
 
 
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_tiers_monotone_live_subsets(seed):
     cfg, _, ec = _get_setup()
@@ -219,7 +218,7 @@ def _oracle_prefix_check(emitted, oracle):
         np.testing.assert_array_equal(col[:n], oracle[:n, r])
 
 
-@settings(max_examples=2)
+@settings(max_examples=2, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_verify_block_prefix_matches_oracle(seed):
     cfg, params, ec = _get_setup()
@@ -350,7 +349,7 @@ def test_two_sided_config_disables_speculation():
     assert spec_out == oracle
 
 
-@settings(max_examples=2)
+@settings(max_examples=2, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_speculative_staggered_arrivals_exact(seed):
     cfg, params, ec = _get_setup()

@@ -192,16 +192,17 @@ def test_reduce_psum_strategies_agree():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.flextree import ReduceConfig, reduce_psum
 
-mesh = jax.make_mesh((8,), ('model',))
+mesh = jax.make_mesh((8,), ('model',),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 16), jnp.float32)
 expect = x.sum(0)
 for strat in ('allreduce', 'tree'):
     cfg = ReduceConfig(axis_name='model', ic_p=8, strategy=strat)
     f = shard_map(lambda v: reduce_psum(v[0], cfg)[None], mesh=mesh,
-                  in_specs=P('model'), out_specs=P('model'), check_rep=False)
+                  in_specs=P('model'), out_specs=P('model'), check_vma=False)
     out = jax.jit(f)(x)
     for i in range(8):
         np.testing.assert_allclose(np.asarray(out[i]), np.asarray(expect),
@@ -210,7 +211,7 @@ for strat in ('allreduce', 'tree'):
 cfg = ReduceConfig(axis_name='model', ic_p=8, strategy='scatter')
 f = shard_map(lambda v: reduce_psum(v[0], cfg, scatter_dim=0)[None],
               mesh=mesh, in_specs=P('model'), out_specs=P('model'),
-              check_rep=False)
+              check_vma=False)
 out = jax.jit(f)(x).reshape(-1)
 np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-5)
 print('reduce strategies OK')

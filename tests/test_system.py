@@ -24,7 +24,8 @@ from repro.configs.base import get_smoke_config, SHAPES
 from repro.launch.step_builders import build_cell_step, lower_cell
 from repro.roofline.hlo import parse_collectives
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke_config('yi-9b')
 shape = dataclasses.replace(SHAPES['train_4k'], seq_len=64, global_batch=4,
                             n_micro=1, loss_chunk=32, attn_chunk=32,
@@ -48,7 +49,8 @@ import dataclasses, jax
 from repro.configs.base import get_smoke_config, SHAPES
 from repro.launch.step_builders import build_cell_step, lower_cell
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke_config('gemma-2b')
 shape = dataclasses.replace(SHAPES['decode_32k'], seq_len=64, global_batch=4)
 step = build_cell_step('gemma-2b', 'decode_32k', mesh, cfg=cfg, shape=shape)

@@ -121,10 +121,11 @@ def test_compressed_mean_shard_map():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.train.grad_compress import CompressConfig, compressed_mean
 
-mesh = jax.make_mesh((8,), ('data',))
+mesh = jax.make_mesh((8,), ('data',),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64), jnp.float32)
 exact = g.mean(0)
 
@@ -135,7 +136,7 @@ for mode, tol in (('int8', 0.05), ('zvc_topk', 1.0), ('none', 1e-6)):
         return r[None], e[None]
     red, err = jax.jit(shard_map(f, mesh=mesh, in_specs=P('data'),
                                  out_specs=(P('data'), P('data')),
-                                 check_rep=False))(g)
+                                 check_vma=False))(g)
     d = float(jnp.abs(red[0] - exact).max())
     assert d < tol, (mode, d)
     if mode == 'int8':

@@ -11,12 +11,11 @@ jit; and runtime activation popcounts must accumulate for calibration.
 """
 import dataclasses
 
+from hypothesis import given, settings, strategies as st
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-
-from conftest import given, settings, strategies as st
 
 from repro.configs.base import SparsityConfig, get_smoke_config
 from repro.core import sparsity as S
@@ -114,7 +113,7 @@ def test_planned_weight_rmatmul_fallback(rng):
 
 
 # ---------------------------------------------------------------------------
-# combine_with_activation_meta ≡ trace-time builder (property, via shim)
+# combine_with_activation_meta ≡ build_block_sparse_meta_jnp (property)
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=25, deadline=None)
