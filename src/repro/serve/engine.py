@@ -108,6 +108,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Sequence
@@ -115,6 +116,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.kernels import ops
@@ -236,6 +238,9 @@ class SamplingParams:
 #   failed          — on-device NaN/Inf quarantine (-2 sentinel)
 #   shed            — bounded-queue overload eviction / rejection
 TERMINAL_STATES = ("done", "cancelled", "deadline_missed", "failed", "shed")
+# lifecycle stamps of a request, in the order it reaches them
+REQUEST_STAMPS = ("submitted", "admitted", "prefilled", "first_token",
+                  "finished")
 
 
 @dataclass
@@ -263,6 +268,16 @@ class Request:
     status: str = "queued"
     # deadline-pressure tier demotions applied (latency_class increments)
     demotions: int = 0
+    # lifecycle stamps on the engine clock (None until reached); see
+    # ``ServeEngine.request_times``
+    submitted: Optional[float] = None
+    admitted: Optional[float] = None      # given a slot
+    prefilled: Optional[float] = None     # last prefill segment dispatched
+    first_token: Optional[float] = None   # first token credited
+    finished: Optional[float] = None      # reached a terminal status
+
+    def times(self) -> Dict[str, Optional[float]]:
+        return {k: getattr(self, k) for k in REQUEST_STAMPS}
 
 
 @dataclass
@@ -542,16 +557,18 @@ class ServeEngine:
         # counters["demotions"])
         self.deadline_demotion = bool(deadline_demotion)
         self.demote_margin = float(demote_margin)
-        # terminal-status accounting: lifetime counters per terminal state
-        # (+ demotions), and a bounded uid -> status map so status(uid)
-        # outlives slot recycling without unbounded growth
+        # lifetime counters: one per terminal state, demotions, and the
+        # work dispatched (admissions, prefill segments with their valid
+        # tokens and padded device steps, fused decode / verify blocks
+        # with their scanned steps, token-block syncs)
         self.counters = {s: 0 for s in TERMINAL_STATES}
-        self.counters["demotions"] = 0
-        self._terminal: "collections.OrderedDict[int, str]" = \
-            collections.OrderedDict()
-        # terminal uid -> credited output tokens (shares _terminal's bound);
-        # ``results()`` reads this after the slot is recycled
-        self._outputs: "collections.OrderedDict[int, List[int]]" = \
+        self.counters.update(demotions=0, admitted=0, prefill_segments=0,
+                             prefill_tokens=0, prefill_steps=0,
+                             decode_blocks=0, decode_steps=0, syncs=0)
+        # terminal uid -> (status, credited output tokens, lifecycle
+        # stamps), bounded so status(), results() and request_times()
+        # outlive slot recycling without unbounded growth
+        self._retired: "collections.OrderedDict[int, tuple]" = \
             collections.OrderedDict()
         # EMA of wall seconds per credited token — the demotion trigger's
         # service-rate estimate (None until two accounted blocks)
@@ -618,11 +635,9 @@ class ServeEngine:
                     f"plan_tiers ratios must be non-decreasing, got "
                     f"{self.tier_ratios}")
         self._compile_tiers(verify=verify_plan)
-        # speculative accounting: lifetime draft/accept counters plus a
-        # per-slot (drafted, accepted) table — the per-site acceptance view
+        # speculative accounting: lifetime draft/accept counters
         self.spec_stats = {"drafted": 0, "accepted": 0, "emitted": 0,
                            "verify_blocks": 0}
-        self.spec_slot_stats = np.zeros((n_slots, 2), np.int64)
         # speculative verify runs the whole k+1 window in ONE batched
         # forward only for families where that is bitwise-equal to k+1
         # sequential steps: plain dense-attention full-cache stacks.
@@ -675,7 +690,10 @@ class ServeEngine:
     # ---- jitted executables ----
     def _scoped(self, fn):
         """Wrap a model function so the engine's exec config (descriptor
-        table, plan, stats collector) is installed at trace time."""
+        table, plan, stats collector) is installed at trace time.  The
+        wrapper keeps ``fn``'s name, which ``jax.jit`` turns into the
+        executable's name in profiler traces (``jit_serve_prefill``)."""
+        @functools.wraps(fn)
         def wrapped(*args, **kwargs):
             if self.exec_cfg is None:
                 return fn(*args, **kwargs)
@@ -687,9 +705,11 @@ class ServeEngine:
         return wrapped
 
     def _build_executables(self):
-        """(Re)build the three jitted entry points.  Called at bring-up and
-        after ``maybe_recalibrate`` swaps the exec config — the new jits
-        re-trace under the new descriptor table on their next call.
+        """(Re)build the jitted entry points ``serve_decode``,
+        ``serve_decode_many``, ``serve_prefill`` and ``serve_verify``
+        (named ``jit_serve_*`` in profiler traces).  Called at bring-up
+        and after ``maybe_recalibrate`` swaps the exec config — the new
+        jits re-trace under the new descriptor table on their next call.
 
         The fused executables donate the decode-state argument (argnum 1):
         the KV / recurrent caches alias in place instead of being copied
@@ -702,25 +722,25 @@ class ServeEngine:
         eos_id = self.eos_id
         nan_guard = self.nan_guard
 
-        def decode_fn(p, t, s, pos, live):
+        def serve_decode(p, t, s, pos, live):
             # the oracle step masks state commits to live rows exactly like
             # the fused block does — done/mid-prefill rows stop writing
             # cache on both paths, and popcounts see live rows only
             return model_lib.masked_decode_step(p, cfg, t, s, pos, live)
 
-        def decode_many_fn(p, s, toks, pos, live, rem, temp, top_k, seeds,
-                           n_steps):
+        def serve_decode_many(p, s, toks, pos, live, rem, temp, top_k,
+                              seeds, n_steps):
             return model_lib.decode_many(p, cfg, toks, s, pos, live, n_steps,
                                          rem=rem, eos_id=eos_id, temp=temp,
                                          top_k=top_k, seeds=seeds,
                                          nan_guard=nan_guard)
 
-        def prefill_fn(p, s, toks, valid, slot, slot_pos, start, reset):
+        def serve_prefill(p, s, toks, valid, slot, slot_pos, start, reset):
             return model_lib.prefill_into_slot(p, cfg, toks, valid, slot, s,
                                                slot_pos, start, reset)
 
-        def verify_fn(p_full, p_draft, s, toks, pos, live, rem, temp, top_k,
-                      seeds, k, windowed):
+        def serve_verify(p_full, p_draft, s, toks, pos, live, rem, temp,
+                         top_k, seeds, k, windowed):
             # one fused speculative block: draft tier proposes k tokens,
             # the full (verify-tier) plan scores all k+1 positions, the
             # longest matching prefix is accepted and the draft's state is
@@ -733,13 +753,13 @@ class ServeEngine:
                                           windowed=windowed,
                                           nan_guard=nan_guard)
 
-        self._decode = jax.jit(self._scoped(decode_fn))
-        self._decode_many = jax.jit(self._scoped(decode_many_fn),
+        self._decode = jax.jit(self._scoped(serve_decode))
+        self._decode_many = jax.jit(self._scoped(serve_decode_many),
                                     static_argnums=(9,),
                                     donate_argnums=donate)
-        self._prefill = jax.jit(self._scoped(prefill_fn),
+        self._prefill = jax.jit(self._scoped(serve_prefill),
                                 donate_argnums=donate)
-        self._verify = jax.jit(self._scoped(verify_fn),
+        self._verify = jax.jit(self._scoped(serve_verify),
                                static_argnums=(10, 11),
                                donate_argnums=((2,) if self.donate_state
                                                else ()))
@@ -922,19 +942,18 @@ class ServeEngine:
         ends.  Idempotent (the first terminal status wins: a cancelled
         request can't be re-finished ``done`` by a late block sync), keeps
         the boolean ``done`` fast path in sync, bumps the lifetime counter
-        and records the status in the bounded uid map ``status()`` reads
-        after the slot is recycled."""
+        and records the request in the bounded uid map ``status()``,
+        ``results()`` and ``request_times()`` read after the slot is
+        recycled."""
         if req.done:
             return
         req.status = status
         req.done = True
+        req.finished = self._clock()
         self.counters[status] += 1
-        self._terminal[req.uid] = status
-        self._outputs[req.uid] = req.out
-        while len(self._terminal) > 4096:
-            self._terminal.popitem(last=False)
-        while len(self._outputs) > 4096:
-            self._outputs.popitem(last=False)
+        self._retired[req.uid] = (status, req.out, req.times())
+        while len(self._retired) > 4096:
+            self._retired.popitem(last=False)
 
     def submit(self, prompt: np.ndarray, max_new: int = 16,
                sampling: Optional[SamplingParams] = None, *,
@@ -984,12 +1003,14 @@ class ServeEngine:
         if deadline is not None and deadline <= 0:
             raise ValueError(f"deadline must be > 0 seconds, got {deadline}")
         self._uid += 1
+        now = self._clock()
         req = Request(self._uid, prompt, max_new=max_new,
                       sampling=sampling,
                       latency_class=int(latency_class),
                       priority=int(priority),
-                      deadline=(self._clock() + deadline
-                                if deadline is not None else None))
+                      deadline=(now + deadline
+                                if deadline is not None else None),
+                      submitted=now)
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             victim = self.admission.shed(self.queue, self, req)
             if victim is None:
@@ -1043,14 +1064,31 @@ class ServeEngine:
         for s in self.slots:
             if s.req is not None and s.req.uid == uid:
                 return s.req.status
-        return self._terminal.get(uid)
+        retired = self._retired.get(uid)
+        return retired[0] if retired is not None else None
+
+    def request_times(self, uid: int) -> Optional[Dict[str, Optional[float]]]:
+        """Lifecycle stamps of a submitted uid on the engine clock:
+        ``submitted``, ``admitted`` (given a slot), ``prefilled`` (last
+        prefill segment dispatched), ``first_token`` (first token
+        credited) and ``finished`` (terminal status reached), each None
+        until reached; ``None`` for unknown uids.  Same retention and
+        snapshot semantics as ``status()``."""
+        for r in self.queue:
+            if r.uid == uid:
+                return r.times()
+        for s in self.slots:
+            if s.req is not None and s.req.uid == uid:
+                return s.req.times()
+        retired = self._retired.get(uid)
+        return dict(retired[2]) if retired is not None else None
 
     def results(self) -> Dict[int, List[int]]:
         """Credited output tokens for every *terminal* request (any status:
         a cancelled/failed request reports the prefix it streamed before
         the fault).  Live requests are excluded — poll ``status()``.  Like
         ``status()``, bounded to the most recent 4096 terminals."""
-        return dict(self._outputs)
+        return {uid: out for uid, (_, out, _) in self._retired.items()}
 
     def _expire_deadlines(self) -> bool:
         """Terminal-mark every request whose deadline has passed on the
@@ -1115,8 +1153,10 @@ class ServeEngine:
     def health(self) -> Dict[str, object]:
         """Engine health snapshot: queue depth, slot occupancy, in-flight
         speculation state, per-request lifecycle statuses for everything
-        the engine currently tracks (queued + slot-bound), lifetime
-        terminal/demotion counters and the speculative-decoding stats.
+        the engine currently tracks (queued + slot-bound), the lifetime
+        ``counters`` (terminal statuses, demotions, admissions, prefill
+        segments / tokens / steps, decode blocks / steps, syncs) and the
+        speculative-decoding stats.
 
         Snapshot semantics — no flush, no device sync: figures reflect
         accounting up to the last synced block (``flush()`` first for
@@ -1176,17 +1216,23 @@ class ServeEngine:
         toks = np.zeros((padded,), np.int32)
         toks[:len(seg)] = seg
         valid = np.arange(padded) < len(seg)
-        self.state = self._prefill(self._exec_params, self.state,
-                                   toks, valid, np.int32(i),
-                                   self._slot_positions(),
-                                   np.int32(start), start == 0)
+        self.counters["prefill_segments"] += 1
+        self.counters["prefill_tokens"] += len(seg)
+        self.counters["prefill_steps"] += padded
+        with TraceAnnotation("serve.prefill.dispatch", uid=s.req.uid,
+                             tokens=len(seg), steps=padded):
+            self.state = self._prefill(self._exec_params, self.state,
+                                       toks, valid, np.int32(i),
+                                       self._slot_positions(),
+                                       np.int32(start), start == 0)
         s.prefill_cursor = start + len(seg)
         s.pos = s.prefill_cursor
+        fed = s.prefill_cursor >= self._feed_len(s.req)
+        if fed:
+            s.req.prefilled = self._clock()
         # lifecycle: the slot is decode-ready once the whole feed landed
         if not s.req.done:
-            s.req.status = ("decode"
-                            if s.prefill_cursor >= self._feed_len(s.req)
-                            else "prefill")
+            s.req.status = "decode" if fed else "prefill"
 
     def _admit(self):
         """Move queued requests into free slots.  The ``admission`` policy
@@ -1203,13 +1249,16 @@ class ServeEngine:
             idx = self.admission.pick(self.queue, self)
             req = self.queue[idx]
             del self.queue[idx]
-            self.slots[i] = _Slot(req=req, pos=0, prefill_cursor=0)
-            feed_len = self._feed_len(req)
-            chunk = self.admission.chunk(self)
-            count = feed_len if chunk is None else min(feed_len, chunk)
-            # feed_len == 0 (length-1 prompt): the call runs one fully
-            # masked step whose only effect is the slot-row zero-reset
-            self._feed_prefill(i, 0, count)
+            with TraceAnnotation("serve.admit", uid=req.uid, slot=i):
+                req.admitted = self._clock()
+                self.counters["admitted"] += 1
+                self.slots[i] = _Slot(req=req, pos=0, prefill_cursor=0)
+                feed_len = self._feed_len(req)
+                chunk = self.admission.chunk(self)
+                count = feed_len if chunk is None else min(feed_len, chunk)
+                # feed_len == 0 (length-1 prompt): the call runs one fully
+                # masked step whose only effect is the slot-row zero-reset
+                self._feed_prefill(i, 0, count)
             admitted = True
         return admitted
 
@@ -1278,6 +1327,8 @@ class ServeEngine:
 
     def _append_token(self, i: int, tok: int, out: Dict[int, int]):
         s = self.slots[i]
+        if not s.req.out:
+            s.req.first_token = self._clock()
         s.req.out.append(tok)
         s.pos += 1
         out[s.req.uid] = tok
@@ -1313,6 +1364,8 @@ class ServeEngine:
                     quarantined = (t == model_lib.QUARANTINE_SENTINEL)
                     toks_i = toks_i[:j]
                     break
+            if toks_i and not s.req.out:
+                s.req.first_token = self._clock()
             s.req.out.extend(toks_i)
             s.pos += len(toks_i)
             out[s.req.uid] = toks_i
@@ -1483,16 +1536,21 @@ class ServeEngine:
         temp, topk, seeds = samp if samp is not None else (None, None, None)
         if spec_k:
             t_block = spec_k + 1
-            block, self.state, dev_tok, dev_pos, dev_rem = self._verify(
-                self._tier_params[tier], self._tier_params[-1], self.state,
-                toks_in, pos_in, self._live_mask(live), rem_in, temp, topk,
-                seeds, spec_k, self._spec_windowed)
-        else:
-            block, self.state, dev_tok, dev_pos, dev_rem = \
-                self._decode_many(
-                    self._tier_params[tier], self.state, toks_in, pos_in,
-                    self._live_mask(live), rem_in, temp, topk, seeds,
-                    t_block)
+        self.counters["decode_blocks"] += 1
+        self.counters["decode_steps"] += t_block
+        with TraceAnnotation("serve.decode.dispatch", steps=t_block,
+                             live=len(live)):
+            if spec_k:
+                block, self.state, dev_tok, dev_pos, dev_rem = self._verify(
+                    self._tier_params[tier], self._tier_params[-1],
+                    self.state, toks_in, pos_in, self._live_mask(live),
+                    rem_in, temp, topk, seeds, spec_k, self._spec_windowed)
+            else:
+                block, self.state, dev_tok, dev_pos, dev_rem = \
+                    self._decode_many(
+                        self._tier_params[tier], self.state, toks_in,
+                        pos_in, self._live_mask(live), rem_in, temp, topk,
+                        seeds, t_block)
         key = self._live_key(live)
         self._carry = (key, dev_tok, dev_pos, dev_rem)
         self._inflight.append(_InflightBlock(key, list(live), t_block,
@@ -1524,12 +1582,11 @@ class ServeEngine:
         requests finished — the occupancy-change signal that invalidates a
         speculatively dispatched successor block's live set."""
         blk = self._inflight.pop(0)
-        # map uid -> slot BEFORE crediting: a finished slot still holds its
-        # request afterwards, but this keeps the stats keyed off the
-        # occupancy the block was dispatched for
-        uid_slot = {self.slots[i].req.uid: i for i in blk.live}
-        credited = self._append_block(blk.live, np.asarray(blk.block),
-                                      blk.t_block)
+        self.counters["syncs"] += 1
+        with TraceAnnotation("serve.sync", steps=blk.t_block):
+            block = np.asarray(blk.block)
+        with TraceAnnotation("serve.account"):
+            credited = self._append_block(blk.live, block, blk.t_block)
         # service-rate EMA (seconds per credited token) between accounted
         # blocks — the deadline-pressure demotion trigger's estimate.  A
         # deterministic VirtualClock that never advances keeps this None/0,
@@ -1556,10 +1613,6 @@ class ServeEngine:
                 self.spec_stats["drafted"] += blk.spec_k
                 self.spec_stats["accepted"] += acc
                 self.spec_stats["emitted"] += len(toks)
-                i = uid_slot.get(uid)
-                if i is not None:
-                    self.spec_slot_stats[i, 0] += blk.spec_k
-                    self.spec_slot_stats[i, 1] += acc
         if out is not None:
             for uid, toks in credited.items():
                 out.setdefault(uid, []).extend(toks)
@@ -1584,9 +1637,8 @@ class ServeEngine:
     def speculative_acceptance(self) -> float:
         """Lifetime draft acceptance rate: accepted drafts / proposed
         drafts over every verify block accounted so far (0.0 before any
-        speculation).  Per-slot (drafted, accepted) counts are in
-        ``spec_slot_stats``.  Call ``flush()`` first to fold any in-flight
-        verify block into the counters."""
+        speculation).  Call ``flush()`` first to fold any in-flight verify
+        block into the counters."""
         d = self.spec_stats["drafted"]
         return self.spec_stats["accepted"] / d if d else 0.0
 
@@ -1644,6 +1696,10 @@ class ServeEngine:
         state.  ``async_dispatch=False`` syncs the block it dispatched
         (classic one-block-per-call behaviour).
         """
+        with TraceAnnotation("serve.tick"):
+            return self._tick(n_steps)
+
+    def _tick(self, n_steps: Optional[int]) -> Dict[int, List[int]]:
         budget = max(1, self.decode_block if n_steps is None else n_steps)
         out: Dict[int, List[int]] = {}
         # failure-path bookkeeping runs first: expiring a request here
