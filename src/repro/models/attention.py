@@ -61,6 +61,32 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bkgqs,bskh->bqkgh", w, v)
 
 
+def cache_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    mask: jax.Array) -> jax.Array:
+    """``dense_attention`` over a decode cache whose rows are flat:
+    q (B,Sq,KVH,G,hd), k/v (B,Skv,KVH·hd), mask (B,1,1,Sq,Skv) bool.
+
+    The cache is read in the layout it is written in: splitting a flat
+    row into (KVH, hd) would relayout the whole cache on TPU.  Instead the
+    queries form a block-diagonal (KVH·hd, KVH·G·Sq) matrix, so each
+    head's scores contract only its own hd block of the row (the other
+    blocks meet exact zeros), and the values' product keeps only each
+    head's own block.  Same products and f32 accumulation as
+    ``dense_attention``, with bf16 scores and output."""
+    b, sq, kvh, g, hd = q.shape
+    n = kvh * g * sq
+    own = jnp.eye(kvh, dtype=bool)[None, :, None, :, None, None]
+    qt = jnp.transpose(q, (0, 4, 2, 3, 1))[:, None]     # (B,1,hd,KVH,G,Sq)
+    qbd = jnp.where(own, qt, jnp.zeros((), q.dtype)).reshape(b, kvh * hd, n)
+    scores = jnp.einsum("bsf,bfn->bns", k, qbd).astype(jnp.float32)
+    scores = scores.reshape(b, kvh, g, sq, -1) * (hd ** -0.5)
+    scores = jnp.where(mask, scores, NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype).reshape(b, n, -1)
+    out = jnp.einsum("bns,bsf->bnf", w, v).reshape(b, kvh, g, sq, kvh, hd)
+    out = jnp.diagonal(out, axis1=1, axis2=4)            # (B,G,Sq,hd,KVH)
+    return jnp.transpose(out, (0, 2, 4, 1, 3))
+
+
 class _Carry(NamedTuple):
     m: jax.Array       # running max      (B,KVH,G,Qc)
     l: jax.Array       # running sum      (B,KVH,G,Qc)
@@ -211,38 +237,46 @@ def attention_forward(p: Params, cfg: ArchConfig, x: jax.Array, *,
 
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
                dtype=jnp.bfloat16) -> Params:
-    """Rolling cache for windowed layers (size=window), else full length."""
+    """Rolling cache for windowed layers (size=window), else full length.
+
+    K and V are held flat, (B, C, KVH·hd): one slot is one contiguous row,
+    which a decode step writes with a single scatter, and at a width of
+    128 lanes or more the row is stored unpadded on TPU in the same layout
+    every executable keeps (see ``cache_attention``)."""
     size = min(cfg.window, max_seq) if cfg.window else max_seq
-    shape = (batch, size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (batch, size, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def decode_step(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
-                pos: jax.Array, *, window: int = 0,
-                memory: Optional[Tuple[jax.Array, jax.Array]] = None,
+                pos: jax.Array, layer: jax.Array, *, window: int = 0,
+                commit: Optional[jax.Array] = None,
                 ) -> Tuple[jax.Array, Params]:
-    """One-token decode.  x (B,1,D); cache k/v (B,C,KVH,hd).
+    """One-token decode through one layer.  x (B,1,D); cache k/v are the
+    whole stack's (L,B,C,KVH·hd) buffers and ``layer`` this layer's index.
+
+    The layer writes its new K/V rows into the stacked buffers at
+    ``(layer, row, slot)`` and then attends over its own layer slice of
+    the updated buffers.  Carried through the layer scan (see
+    ``transformer.decode_stack``), the buffers are updated in place: a
+    step writes each row's slot in each layer and copies nothing else.
 
     ``pos`` is either a scalar (every sequence at the same depth — the
     original lockstep serving path and the dry-run decode cells) or a (B,)
     vector of per-sequence positions (the continuous-batching engine, where
-    staggered admits leave every slot at its own depth).  The scalar path is
-    kept verbatim: the vector path generalizes the cache write to a per-row
-    scatter and the validity mask to per-row position bounds.
+    staggered admits leave every slot at its own depth).  The scalar path
+    writes one ``dynamic_update_slice``; the vector path a per-row scatter,
+    and the validity mask becomes per-row position bounds.
 
-    ``memory`` short-circuits to cross-attention (whisper decoder): attends
-    to the fixed (k_mem, v_mem) without cache updates.
+    ``commit`` (B,) bool masks what the step commits (None: every row).
+    Every row writes its slot before the attention, so each row attends
+    over its own token exactly as when the whole state was computed and
+    then selected; a row outside ``commit`` then gets its slot's old
+    value back, so its cache stays bit-untouched.  Windowed layers keep a
+    ring of ``window`` slots (``pos % size``).
     """
     b = x.shape[0]
-    hd, kvh = cfg.head_dim, cfg.n_kv_heads
-    if memory is not None:
-        q = ops.flex_matmul(x, p["wq"], site="attn.q").reshape(
-            b, 1, kvh, cfg.q_per_kv, hd)
-        k_mem, v_mem = memory
-        o = dense_attention(q, k_mem, v_mem, None)
-        o = o.reshape(b, 1, cfg.n_heads * hd)
-        return ops.flex_matmul(o, p["wo"], site="attn.out"), cache
-
+    hd = cfg.head_dim
     pos = jnp.asarray(pos, jnp.int32)
     per_slot = pos.ndim == 1
     q, k_new, v_new = _project_qkv(p, cfg, x)
@@ -253,19 +287,15 @@ def decode_step(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
     q = qf.reshape(q.shape)
     k_new = rope.apply_rope(k_new, posb, kind=cfg.rope, theta=cfg.rope_theta)
 
-    size = cache["k"].shape[1]
+    size = cache["k"].shape[2]
     slot = (pos % size) if window > 0 else jnp.minimum(pos, size - 1)
-    if per_slot:
-        rows = jnp.arange(b)
-        k = cache["k"].at[rows, slot].set(k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[rows, slot].set(v_new[:, 0].astype(cache["v"].dtype))
-    else:
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-    k = shard(k, "cache_batch", "cache_seq", None, None)
-    v = shard(v, "cache_batch", "cache_seq", None, None)
+    news = {"k": k_new.reshape(b, -1), "v": v_new.reshape(b, -1)}
+    if commit is not None:
+        kept = {name: _read_rows(cache[name], layer, slot) for name in news}
+    cache = {name: _write_rows(cache[name], news[name], layer, slot)
+             for name in news}
+    k = jax.lax.dynamic_index_in_dim(cache["k"], layer, 0, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(cache["v"], layer, 0, keepdims=False)
 
     # validity mask over cache slots; per-row when pos is a vector
     idx = jnp.arange(size)[None] if per_slot else jnp.arange(size)
@@ -277,10 +307,54 @@ def decode_step(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
         valid = idx <= posm
     mask = (valid[:, None, None, None, :] if per_slot
             else valid[None, None, None, None, :])
-    o = dense_attention(q, k, v, mask)
+    o = cache_attention(q, k, v, mask)
+    if commit is not None:
+        # the barrier orders the put-back after every read of the slice
+        o, kept = jax.lax.optimization_barrier((o, kept))
+        cache = {name: _write_rows(cache[name], kept[name], layer, slot,
+                                   ~commit) for name in cache}
+    cache = {name: shard(a, None, "cache_batch", "cache_seq", None)
+             for name, a in cache.items()}
     o = o.reshape(b, 1, cfg.n_heads * hd)
     out = ops.flex_matmul(o, p["wo"], site="attn.out")
-    return out, {"k": k, "v": v}
+    return out, cache
+
+
+def _read_rows(buf: jax.Array, layer: jax.Array, slot: jax.Array
+               ) -> jax.Array:
+    """Each row's (KVH·hd) value at its ``slot`` of the stacked cache
+    ``buf`` (L,B,C,KVH·hd) at ``layer``: (B, KVH·hd)."""
+    if slot.ndim == 1:
+        return buf[layer, jnp.arange(buf.shape[1]), slot]
+    return jax.lax.dynamic_slice(
+        buf, (layer, 0, slot, 0), (1, buf.shape[1], 1, buf.shape[3]))[0, :, 0]
+
+
+def _write_rows(buf: jax.Array, new: jax.Array, layer: jax.Array,
+                slot: jax.Array, rows: Optional[jax.Array] = None
+                ) -> jax.Array:
+    """Write one row per sequence, ``new`` (B, KVH·hd), into the stacked
+    cache ``buf`` (L,B,C,KVH·hd) at ``layer``, for the (B,) ``rows`` mask
+    (None: every row).
+
+    A (B,) ``slot`` scatters each row to its own slot, and a row outside
+    ``rows`` gets an out-of-range row index, which the scatter drops.  A
+    scalar ``slot`` writes the (1,B,1,KVH·hd) slab with
+    ``dynamic_update_slice``, putting back the old value of the rows
+    outside ``rows``."""
+    b = new.shape[0]
+    new = new.astype(buf.dtype)
+    if slot.ndim == 1:
+        idx = jnp.arange(b)
+        if rows is not None:
+            idx = jnp.where(rows, idx, b)
+        return buf.at[layer, idx, slot].set(new, mode="drop")
+    upd = new[None, :, None]
+    start = (layer, 0, slot, 0)
+    if rows is not None:
+        old = jax.lax.dynamic_slice(buf, start, upd.shape)
+        upd = jnp.where(rows[None, :, None, None], upd, old)
+    return jax.lax.dynamic_update_slice(buf, upd, start)
 
 
 def decode_window(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
@@ -288,8 +362,9 @@ def decode_window(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
     """W-position batched decode — the speculative-verify scorer.
 
     x (B, W, D) holds W consecutive tokens per row, ``pos`` (B,) the
-    sequence position of each row's *first* window token.  Full-length
-    caches only (``cfg.window == 0``): all W K/V pairs are scattered into
+    sequence position of each row's *first* window token; cache k/v are
+    one layer's (B,C,KVH·hd).  Full-length caches only
+    (``cfg.window == 0``): all W K/V pairs are scattered into
     the cache first, then every query attends the whole cache under a
     per-(row, query) validity mask ``idx <= pos + i`` — causal over the
     prefix *and* within the window (query i sees keys ≤ its own position,
@@ -313,14 +388,16 @@ def decode_window(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
     size = cache["k"].shape[1]
     slots = jnp.minimum(posw, size - 1)
     rows = jnp.arange(b)[:, None]
-    k = cache["k"].at[rows, slots].set(k_new.astype(cache["k"].dtype))
-    v = cache["v"].at[rows, slots].set(v_new.astype(cache["v"].dtype))
-    k = shard(k, "cache_batch", "cache_seq", None, None)
-    v = shard(v, "cache_batch", "cache_seq", None, None)
+    k = cache["k"].at[rows, slots].set(
+        k_new.reshape(b, w, -1).astype(cache["k"].dtype))
+    v = cache["v"].at[rows, slots].set(
+        v_new.reshape(b, w, -1).astype(cache["v"].dtype))
+    k = shard(k, "cache_batch", "cache_seq", None)
+    v = shard(v, "cache_batch", "cache_seq", None)
 
     idx = jnp.arange(size)
     valid = idx[None, None, :] <= posw[:, :, None]               # (B, W, C)
-    o = dense_attention(q, k, v, valid[:, None, None])
+    o = cache_attention(q, k, v, valid[:, None, None])
     o = o.reshape(b, w, cfg.n_heads * hd)
     return ops.flex_matmul(o, p["wo"], site="attn.out"), {"k": k, "v": v}
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.kernels import ops
-from repro.models import attention, transformer
+from repro.models import transformer
 from repro.models.layers import (apply_norm, chunked_softmax_xent, embed,
                                  init_embedding, init_norm, logits_head)
 from repro.models.unroll import maybe_unrolled_scan
@@ -122,57 +122,23 @@ def prefill(p: Params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
     return logits_head(cfg, head_matrix(p, cfg), x[:, -1:, :])
 
 
-def prefill_with_cache(p: Params, cfg: ArchConfig,
-                       batch: Dict[str, jax.Array], max_seq: int, *,
-                       dtype=jnp.bfloat16
-                       ) -> Tuple[jax.Array, Params]:
-    """Prompt pass that also fills the decode state (dense families).
-
-    Serving path for plain dense stacks; heterogeneous families fall back to
-    token-by-token prefill in the engine (see ``serve.engine``).
-    """
-    assert not (cfg.encoder_decoder or cfg.ssm.enabled or cfg.rglru.enabled
-                or cfg.moe.enabled), "cache-filling prefill: dense only"
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    x = embed(cfg, p["embed"], tokens)
-    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    size = min(cfg.window, max_seq) if cfg.window else max_seq
-
-    def body(h, lp):
-        y = apply_norm(lp["ln1"], cfg, h)
-        o, (k, v) = attention.attention_forward(
-            lp["attn"], cfg, y, positions=positions, window=cfg.window,
-            return_kv=True)
-        h = h + o
-        y = apply_norm(lp["ln2"], cfg, h)
-        from repro.models.layers import apply_mlp
-        h = h + apply_mlp(lp["mlp"], cfg, y)
-        pad = size - k.shape[1]
-        kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(dtype)
-        vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(dtype)
-        return h, {"k": kc, "v": vc}
-
-    x, caches = jax.lax.scan(body, x, p["stack"]["layers"])
-    x = apply_norm(p["final_norm"], cfg, x)
-    logits = logits_head(cfg, head_matrix(p, cfg), x[:, -1:, :])
-    return logits, {"layers": caches}
-
-
 def init_decode_state(cfg: ArchConfig, batch: int, max_seq: int,
                       dtype=jnp.bfloat16) -> Params:
     return transformer.init_decode_state(cfg, batch, max_seq, dtype)
 
 
 def decode_step(p: Params, cfg: ArchConfig, tokens: jax.Array, state: Params,
-                pos: jax.Array) -> Tuple[jax.Array, Params]:
+                pos: jax.Array, commit: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, Params]:
     """One new token for every sequence.  tokens (B, 1) → logits (B, 1, V).
 
     ``pos`` is a scalar (lockstep) or a (B,) vector of per-sequence
-    positions (see ``attention.decode_step``).
+    positions (see ``attention.decode_step``).  ``commit`` (B,) bool masks
+    which rows commit state (None: every row; see ``masked_decode_step``).
     """
     x = embed(cfg, p["embed"], tokens)
-    x, state = transformer.decode_stack(p["stack"], cfg, x, state, pos)
+    x, state = transformer.decode_stack(p["stack"], cfg, x, state, pos,
+                                        commit)
     x = apply_norm(p["final_norm"], cfg, x)
     return logits_head(cfg, head_matrix(p, cfg), x), state
 
@@ -193,13 +159,18 @@ def masked_decode_step(p: Params, cfg: ArchConfig, tokens: jax.Array,
     between its chunks, and a done row stops writing cache.  The mask is
     also installed as the popcount row filter (``ops.active_rows``) so
     runtime activation densities count live rows only.
+
+    Commits are masked per row, in place, inside the layer scan
+    (``transformer.decode_stack``): each layer writes every row's K/V slot
+    into the stacked cache, attends, and puts back the slot of each
+    inactive row (so filler rows compute exactly what they did under a
+    whole-state select, which batch-coupled MoE routing sees); recurrent
+    leaves (SSM, RG-LRU) are selected per row on each layer's slice, and
+    read-only leaves (the encoder-decoder ``memory``) pass through.  No
+    step computes a whole new state to select from.
     """
     with ops.active_rows(active):
-        logits, new = decode_step(p, cfg, tokens, state, pos)
-    state = jax.tree.map(
-        lambda old, nw: jnp.where(_batch_mask(active, old), nw, old),
-        state, new)
-    return logits, state
+        return decode_step(p, cfg, tokens, state, pos, active)
 
 
 def sample_tokens(logits: jax.Array, temp: jax.Array, top_k: jax.Array,
@@ -504,6 +475,20 @@ def verify_block(p_full: Params, p_draft: Params, cfg: ArchConfig,
     return jnp.stack(emits), state, tok, ps, rm
 
 
+def _reset_row(state: Params, row: jax.Array, reset: jax.Array) -> Params:
+    """Zero batch row ``row`` of every stacked state leaf (L, B, ...) where
+    ``reset`` holds: one row slab per leaf, written in place with
+    ``dynamic_update_slice``."""
+    reset = jnp.asarray(reset, bool)
+
+    def one(a):
+        start = (0, row) + (0,) * (a.ndim - 2)
+        old = jax.lax.dynamic_slice(a, start, (a.shape[0], 1) + a.shape[2:])
+        return jax.lax.dynamic_update_slice(
+            a, jnp.where(reset, jnp.zeros_like(old), old), start)
+    return jax.tree.map(one, state)
+
+
 def prefill_into_slot(p: Params, cfg: ArchConfig, tokens: jax.Array,
                       valid: jax.Array, slot: jax.Array, state: Params,
                       slot_pos: jax.Array, start: jax.Array = 0,
@@ -538,11 +523,11 @@ def prefill_into_slot(p: Params, cfg: ArchConfig, tokens: jax.Array,
     onehot = jnp.arange(b) == slot
     # zero-reset the admitted row: recurrent families (SSM / RG-LRU) carry
     # state across tokens, and the freed slot's old trajectory must not
-    # bleed into the new request (KV rows are masked by position anyway)
-    reset_row = onehot & jnp.asarray(reset, bool)
-    state = jax.tree.map(
-        lambda a: jnp.where(_batch_mask(reset_row, a), jnp.zeros_like(a), a),
-        state)
+    # bleed into the new request.  KV slots past the position are masked,
+    # but a masked slot still enters the value sum with weight 0, and
+    # 0 * NaN is NaN: a row quarantined by ``nan_guard`` would poison its
+    # slot's next request.
+    state = _reset_row(state, slot, reset)
     start = jnp.asarray(start, jnp.int32)
 
     def step(st, inp):

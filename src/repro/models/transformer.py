@@ -73,11 +73,11 @@ def apply_dense_layer(p: Params, cfg: ArchConfig, x: jax.Array, *,
     return x + apply_mlp(p["mlp"], cfg, h)
 
 
-def decode_dense_layer(p: Params, cfg: ArchConfig, x, cache, pos, *,
-                       window: int = 0):
+def decode_dense_layer(p: Params, cfg: ArchConfig, x, cache, pos, layer, *,
+                       window: int = 0, commit=None):
     h = apply_norm(p["ln1"], cfg, x)
-    o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos,
-                                     window=window)
+    o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos, layer,
+                                     window=window, commit=commit)
     x = x + o
     h = apply_norm(p["ln2"], cfg, x)
     return x + apply_mlp(p["mlp"], cfg, h), cache
@@ -104,9 +104,11 @@ def apply_moe_layer(p: Params, cfg: ArchConfig, x: jax.Array, *,
     return x + moe_mod.apply_moe(p["moe"], cfg, h)
 
 
-def decode_moe_layer(p: Params, cfg: ArchConfig, x, cache, pos):
+def decode_moe_layer(p: Params, cfg: ArchConfig, x, cache, pos, layer, *,
+                     commit=None):
     h = apply_norm(p["ln1"], cfg, x)
-    o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos)
+    o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos, layer,
+                                     commit=commit)
     x = x + o
     h = apply_norm(p["ln2"], cfg, x)
     return x + moe_mod.apply_moe(p["moe"], cfg, h), cache
@@ -186,16 +188,21 @@ def apply_griffin_group(p: Params, cfg: ArchConfig, x, *, positions,
     return x
 
 
-def decode_griffin_group(p: Params, cfg: ArchConfig, x, state, pos):
-    new_state = {}
+def decode_griffin_group(p: Params, cfg: ArchConfig, x, kv, rec, pos, layer,
+                         *, commit=None):
+    """One (rec, rec, attn) group: ``kv`` holds the stacked caches of its
+    attention blocks, ``rec`` this group's slice of its recurrent states."""
+    kv, rec = dict(kv), dict(rec)
     for i, kind in enumerate(cfg.rglru.block_pattern):
         key = f"b{i}_{kind}"
         if kind == "rec":
-            x, new_state[key] = decode_rec_layer(p[key], cfg, x, state[key])
+            x, new = decode_rec_layer(p[key], cfg, x, rec[key])
+            rec[key] = _commit_rows(commit, new, rec[key])
         else:
-            x, new_state[key] = decode_dense_layer(
-                p[key], cfg, x, state[key], pos, window=cfg.window)
-    return x, new_state
+            x, kv[key] = decode_dense_layer(
+                p[key], cfg, x, kv[key], pos, layer, window=cfg.window,
+                commit=commit)
+    return x, kv, rec
 
 
 def init_stack(cfg: ArchConfig, rng, dtype=jnp.bfloat16) -> Params:
@@ -382,8 +389,39 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_seq: int,
     return st
 
 
+def _commit_rows(commit: Optional[jax.Array], new: Params, old: Params
+                ) -> Params:
+    """Per-row select of one layer's recurrent state (B, ...) leaves: rows
+    outside ``commit`` keep ``old`` bit-for-bit (None commits every row)."""
+    if commit is None:
+        return new
+    return jax.tree.map(
+        lambda n, o: jnp.where(
+            commit.reshape(commit.shape + (1,) * (o.ndim - 1)), n, o),
+        new, old)
+
+
+def _scan_layers(step, x: jax.Array, params: Params, kv: Params, xs=None):
+    """Scan ``step(lp, h, kv, xs_l, layer) -> (h, kv, ys_l)`` over stacked
+    layers.  The stacked KV caches ``kv`` ride whole in the carry, so each
+    layer writes its rows into one buffer at its own ``layer`` index and
+    nothing is restacked; ``xs`` (recurrent state, read-only memory) is
+    sliced per layer and ``ys`` restacked, as small leaves afford."""
+    n = jax.tree.leaves(params)[0].shape[0]
+
+    def body(carry, inp):
+        h, kv = carry
+        lp, xl, layer = inp
+        h, kv, yl = step(lp, h, kv, xl, layer)
+        return (h, kv), yl
+    (x, kv), ys = _lax_scan(body, (x, kv),
+                            (params, xs, jnp.arange(n, dtype=jnp.int32)))
+    return x, kv, ys
+
+
 def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
-                 pos: jax.Array) -> Tuple[jax.Array, Params]:
+                 pos: jax.Array, commit: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, Params]:
     """One-token step through the full stack.  x (B,1,D).
 
     ``pos`` is a scalar or a (B,) per-sequence position vector — it flows
@@ -393,82 +431,82 @@ def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
     the whole stack body is what ``model.decode_many`` scans over T steps —
     every state leaf returned here threads through that scan carry, so
     state layouts must stay (L, B, ...) with batch at axis 1.
-    """
-    def scan_kind(params_s, state_s, step):
-        def body(h, inp):
-            lp, st = inp
-            h, st = step(lp, h, st)
-            return h, st
-        return _lax_scan(body, x, (params_s, state_s))
 
+    ``commit`` (B,) bool masks which rows commit state (None: every row),
+    by leaf kind: KV caches are written in place, per row, at each layer's
+    index of the stacked buffer; recurrent state (SSM, RG-LRU) is selected
+    per row on each layer's slice; the encoder-decoder's cross-attention
+    ``memory`` is read-only and passes through untouched.
+    """
     if cfg.encoder_decoder:
-        def body(h, inp):
-            lp, st, mem_k, mem_v = inp
+        def dec_step(lp, h, kv, mem, layer):
             y = apply_norm(lp["ln1"], cfg, h)
-            o, st = attention.decode_step(lp["attn"], cfg, y, st, pos)
+            o, kv = attention.decode_step(lp["attn"], cfg, y, kv, pos, layer,
+                                          commit=commit)
             h = h + o
             y = apply_norm(lp["lnx"], cfg, h)
             b = y.shape[0]
             q = (y @ lp["xattn"]["wq"]).reshape(
                 b, 1, cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim)
-            o = attention.dense_attention(q, mem_k, mem_v, None)
+            o = attention.dense_attention(q, mem["k"], mem["v"], None)
             h = h + o.reshape(b, 1, -1) @ lp["xattn"]["wo"]
             y = apply_norm(lp["ln2"], cfg, h)
-            return h + apply_mlp(lp["mlp"], cfg, y), st
-        x_out, new_self = _lax_scan(
-            body, x, (p["decoder"], state["self"],
-                      state["memory"]["k"], state["memory"]["v"]))
-        return x_out, {"self": new_self, "memory": state["memory"]}
+            return h + apply_mlp(lp["mlp"], cfg, y), kv, None
+        x_out, kv, _ = _scan_layers(dec_step, x, p["decoder"], state["self"],
+                                    state["memory"])
+        return x_out, {"self": kv, "memory": state["memory"]}
+
+    def rec_step(decode_layer):
+        def step(lp, h, kv, st, layer):
+            h, new = decode_layer(lp, cfg, h, st)
+            return h, kv, _commit_rows(commit, new, st)
+        return step
 
     if cfg.ssm.enabled:
-        x_out, st = scan_kind(p["layers"], state["layers"],
-                              lambda lp, h, s: decode_ssm_layer(lp, cfg, h, s))
+        x_out, _, st = _scan_layers(rec_step(decode_ssm_layer), x,
+                                    p["layers"], {}, state["layers"])
         return x_out, {"layers": st}
 
     if cfg.rglru.enabled:
-        def g_body(h, inp):
-            lp, st = inp
-            h, st = decode_griffin_group(lp, cfg, h, st, pos)
-            return h, st
-        x_out, gst = _lax_scan(g_body, x, (p["groups"], state["groups"]))
-        new = {"groups": gst}
+        attn = {f"b{i}_{kind}" for i, kind in enumerate(cfg.rglru.block_pattern)
+                if kind != "rec"}
+        groups = state["groups"]
+
+        def g_step(lp, h, kv, rec, layer):
+            return decode_griffin_group(lp, cfg, h, kv, rec, pos, layer,
+                                        commit=commit)
+        x_out, kv, rec = _scan_layers(
+            g_step, x, p["groups"],
+            {k: v for k, v in groups.items() if k in attn},
+            {k: v for k, v in groups.items() if k not in attn})
+        new = {"groups": {**rec, **kv}}
         if "trailing" in p:
-            def t_body(h, inp):
-                lp, st = inp
-                h, st = decode_rec_layer(lp, cfg, h, st)
-                return h, st
-            x_out, tst = _lax_scan(t_body, x_out,
-                                      (p["trailing"], state["trailing"]))
-            new["trailing"] = tst
+            x_out, _, new["trailing"] = _scan_layers(
+                rec_step(decode_rec_layer), x_out, p["trailing"], {},
+                state["trailing"])
         return x_out, new
+
+    def kv_step(decode_layer, **kw):
+        def step(lp, h, kv, _, layer):
+            h, kv = decode_layer(lp, cfg, h, kv, pos, layer, commit=commit,
+                                 **kw)
+            return h, kv, None
+        return step
 
     if cfg.moe.enabled:
         new = {}
         x_out = x
         if "dense_layers" in p:
-            def d_body(h, inp):
-                lp, st = inp
-                h, st = decode_dense_layer(lp, cfg, h, st, pos)
-                return h, st
-            x_out, dst = _lax_scan(d_body, x_out,
-                                      (p["dense_layers"],
-                                       state["dense_layers"]))
-            new["dense_layers"] = dst
-        def m_body(h, inp):
-            lp, st = inp
-            h, st = decode_moe_layer(lp, cfg, h, st, pos)
-            return h, st
-        x_out, mst = _lax_scan(m_body, x_out, (p["layers"],
-                                                  state["layers"]))
-        new["layers"] = mst
+            x_out, new["dense_layers"], _ = _scan_layers(
+                kv_step(decode_dense_layer), x_out, p["dense_layers"],
+                state["dense_layers"])
+        x_out, new["layers"], _ = _scan_layers(
+            kv_step(decode_moe_layer), x_out, p["layers"], state["layers"])
         return x_out, new
 
-    def body(h, inp):
-        lp, st = inp
-        h, st = decode_dense_layer(lp, cfg, h, st, pos, window=cfg.window)
-        return h, st
-    x_out, st = _lax_scan(body, x, (p["layers"], state["layers"]))
-    return x_out, {"layers": st}
+    x_out, kv, _ = _scan_layers(kv_step(decode_dense_layer, window=cfg.window),
+                                x, p["layers"], state["layers"])
+    return x_out, {"layers": kv}
 
 
 def decode_stack_window(p: Params, cfg: ArchConfig, x: jax.Array,

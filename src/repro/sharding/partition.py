@@ -226,13 +226,14 @@ _BATCH_INPUT_AXES: Dict[str, Tuple[Optional[str], ...]] = {
 }
 
 # decode-state param-path patterns (leading layer-stack dim prepended):
-#   kv caches   (B, C, KVH, hd) : batch, cache_seq, -, -
+#   kv caches   (B, C, KVH·hd)  : batch, cache_seq, -
+#   cross-attention memory (B, C, KVH, hd) : batch, cache_seq, -, -
 #   ssm state   (B, H, P, N)    : batch, model(heads), -, -
 #   conv state  (B, K-1, C)     : batch, -, model(channels)
 #   rglru h     (B, W)          : batch, model
 _STATE_RULES: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
-    (r".*(memory|self|layers|groups|trailing).*/(k|v)$",
-     ("batch", "cache_seq", None, None)),
+    (r".*memory.*/(k|v)$", ("batch", "cache_seq", None, None)),
+    (r".*(self|layers|groups).*/(k|v)$", ("batch", "cache_seq", None)),
     (r".*ssm$", ("batch", "heads", None, None)),
     (r".*conv$", ("batch", None, "ffn")),
     (r".*/h$", ("batch", "ffn")),

@@ -97,7 +97,7 @@ for path, s in paths.items():
 specs = M.input_specs(cfg, __import__('repro.configs.base', fromlist=['SHAPES']).SHAPES['decode_32k'])
 bs = batch_shardings(specs, mesh, seq_shard=True)
 k_sh = tree_paths(bs)['state/layers/k']
-assert str(k_sh.spec[2]) == 'model', k_sh.spec      # (L, B, C, KVH, hd)
+assert str(k_sh.spec[2]) == 'model', k_sh.spec      # (L, B, C, KVH*hd)
 assert str(k_sh.spec[1]) == 'data', k_sh.spec
 print('partition rules OK')
 """)

@@ -1,17 +1,22 @@
-"""Ahead-of-time compiles of the Pallas kernels for one TPU v5e chip.
+"""Ahead-of-time compiles for one TPU v5e chip: the Pallas kernels and the
+serving loop's decode-state commit.
 
 Each kernel is lowered and compiled by the TPU compiler from shapes alone,
 at StableLM-2-1.6B widths (d_model 2048, d_ff 5632, vocab 100352, head_dim
 64) with M = 8 decode rows and the block shapes the schedule selector picks
 for those sites.  The chip is described, not attached: nothing runs, so
 this says nothing about results or times.  It catches what interpret mode
-cannot — Mosaic layout and tiling refusals.
+cannot — Mosaic layout and tiling refusals.  The fused decode block and
+the prefill segment are compiled at the model's full size, and their
+programs are read for what they do to the stacked KV cache: update it in
+place, with no copy, relayout or whole-state select.
 
 The topology is described inside a module-scoped fixture, never at import,
 so collecting this file loads no TPU library; where no topology can be
 described, the tests skip from that fixture.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -140,3 +145,72 @@ def test_flash_attention_compiles(one_chip):
     qkv = _shape((N_HEADS, 2048, HEAD_DIM), jnp.bfloat16, one_chip)
     _compile_for_chip(lambda q, k, v: flash_attention(q, k, v, causal=True),
                       qkv, qkv, qkv)
+
+
+def _state_producers(hlo: str, shape: str):
+    """(op, fused root op) of every instruction of the compiled ``hlo``
+    whose result has the array type ``shape`` (e.g. ``bf16[2,8]``)."""
+    roots, comp = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+        root = re.match(r"\s*ROOT %\S+ = \S+ ([\w\-]+)\(", line)
+        if root:
+            roots[comp] = root.group(1)
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r"= " + re.escape(shape) + r"\{[^}]*\} ([\w\-]+)\(",
+                      line)
+        if m:
+            calls = re.search(r"calls=%([\w.\-]+)", line)
+            out.append((m.group(1), calls and roots.get(calls.group(1))))
+    return out
+
+
+def test_decode_state_commits_in_place(one_chip):
+    """At full size, ``decode_many`` (a 16-step block) and
+    ``prefill_into_slot`` (a 128-token segment) with the state donated
+    write the stacked K/V cache only through in-place scatters and
+    ``dynamic-update-slice``s: no copy, relayout or select of it, and the
+    state output aliases the state input."""
+    from repro.models import model as model_lib
+    cfg = get_config("stablelm-1.6b")
+    n_slots, max_seq = M, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _shape(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: model_lib.init_params(
+        cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: model_lib.init_decode_state(
+        cfg, n_slots, max_seq)))
+    k = state["layers"]["k"]
+    shape = "bf16[{}]".format(",".join(map(str, k.shape)))
+    rows = _shape((n_slots,), jnp.int32, one_chip)
+    flags = _shape((n_slots,), jnp.bool_, one_chip)
+    scalar = _shape((), jnp.int32, one_chip)
+    decode = jax.jit(
+        lambda p, s, t, pos, live, rem: model_lib.decode_many(
+            p, cfg, t, s, pos, live, 16, rem=rem),
+        donate_argnums=(1,)).lower(params, state, rows, rows, flags, rows)
+    prefill = jax.jit(
+        lambda p, s, t, v, slot, sp, start, reset: model_lib.prefill_into_slot(
+            p, cfg, t, v, slot, s, sp, start, reset),
+        donate_argnums=(1,)).lower(
+            params, state, _shape((128,), jnp.int32, one_chip),
+            _shape((128,), jnp.bool_, one_chip), scalar, rows, scalar,
+            _shape((), jnp.bool_, one_chip))
+    writes = {("scatter", None), ("fusion", "scatter"),
+              ("dynamic-update-slice", None),
+              ("fusion", "dynamic-update-slice")}
+    passes = {("parameter", None), ("get-tuple-element", None),
+              ("bitcast", None)}
+    for name, lowered in (("decode_many", decode), ("prefill", prefill)):
+        hlo = lowered.compile().as_text()
+        producers = set(_state_producers(hlo, shape))
+        assert producers & writes, name
+        assert producers <= writes | passes, (name, producers - writes - passes)
+        header = hlo.splitlines()[0]        # HloModule ..., input_output_alias
+        assert header.count("may-alias") >= 2, name     # k and v donated
