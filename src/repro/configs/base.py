@@ -19,17 +19,39 @@ from typing import Optional, Sequence
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 0            # routed experts
+    n_experts: int = 0            # routed experts (the router's width)
     n_shared: int = 0             # always-on shared experts
     top_k: int = 1
     expert_d_ff: int = 0          # per-expert hidden size
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     first_dense_layers: int = 0   # leading layers that use a dense MLP instead
+    # the routed experts this chip holds, [expert_offset, expert_offset +
+    # experts_held) of n_experts: one chip's share of an expert-parallel
+    # deployment (0 = all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
+    # renormalise the top-k gates to sum to 1 (DeepSeek-MoE does not)
+    norm_topk_prob: bool = True
+
+    def __post_init__(self):
+        if self.experts_held == 0:
+            object.__setattr__(self, "experts_held", self.n_experts)
+        if not (0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.n_experts):
+            raise ValueError(
+                f"held experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.experts_held}) do not lie in "
+                f"the {self.n_experts} routed experts")
 
     @property
     def enabled(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def partial(self) -> bool:
+        """True where this chip holds only a share of the routed experts."""
+        return self.experts_held < self.n_experts
 
 
 @dataclass(frozen=True)
@@ -105,6 +127,15 @@ class ArchConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        # a configuration read from JSON gives its groups as dicts
+        for key, kind in (("moe", MoEConfig), ("ssm", SSMConfig),
+                          ("rglru", RGLRUConfig)):
+            value = getattr(self, key)
+            if isinstance(value, dict):
+                if "block_pattern" in value:
+                    value = {**value,
+                             "block_pattern": tuple(value["block_pattern"])}
+                object.__setattr__(self, key, kind(**value))
 
     @property
     def q_per_kv(self) -> int:

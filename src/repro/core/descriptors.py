@@ -112,12 +112,15 @@ def matmul_sites(cfg: ArchConfig, shape: ShapeConfig,
 
     if cfg.moe.enabled:
         sites.append(("moe.router", tokens, cfg.moe.n_experts, d))
-        cap = int(tokens * cfg.moe.top_k / cfg.moe.n_experts
-                  * cfg.moe.capacity_factor) + 1
-        f = cfg.moe.expert_d_ff
         # batched-expert einsum sites (E, C, K) × (E, K, N): per-expert
-        # (M, N, K) with M = capacity-padded tokens per expert; one schedule
-        # (and one PlannedWeight max_nnz) shared across the E experts
+        # (M, N, K) with M = capacity-padded tokens per expert, or every
+        # token at decode (the dropless serving layer runs each held expert
+        # on every row); one schedule (and one PlannedWeight max_nnz)
+        # shared across the E experts
+        cap = (tokens if shape.kind == "decode" else
+               int(tokens * cfg.moe.top_k / cfg.moe.n_experts
+                   * cfg.moe.capacity_factor) + 1)
+        f = cfg.moe.expert_d_ff
         sites.append(("moe.experts_in", cap, f, d))
         sites.append(("moe.experts_gate", cap, f, d))
         sites.append(("moe.experts_out", cap, d, f))
@@ -252,7 +255,7 @@ def site_plan_estimate(d: SiteDescriptor, cfg: ArchConfig,
     # the worst-loaded device)
     n_mats = 1
     if d.site.startswith("moe.experts") and cfg.moe.enabled:
-        n_mats = -(-cfg.moe.n_experts // model_shards)
+        n_mats = -(-cfg.moe.experts_held // model_shards)
     dense_bytes = d.k * d.n * in_bytes * n_mats
     zvc_bytes = (dense_bytes * wt_d + n_mats * d.k * d.n / 8.0 if sparse
                  else float(dense_bytes))

@@ -493,9 +493,11 @@ def flex_expert_matmul(x: jax.Array, w, *, site: str = "") -> jax.Array:
     on; on the XLA path they fall back to the batched einsum, bit-identical
     to the pre-dispatch path.
 
-    NOTE on popcounts: ``x`` is the capacity-padded dispatch buffer, so the
-    recorded two-sided activation density folds routing occupancy (invalid
-    capacity slots are zero rows) into activation sparsity.  That is the
+    NOTE on popcounts: in the capacity-bounded forward ``x`` is the
+    capacity-padded dispatch buffer, so the recorded two-sided activation
+    density folds routing occupancy (invalid capacity slots are zero rows)
+    into activation sparsity (the serving layer feeds every row to every
+    held expert, so there it does not).  That is the
     density the expert matmul *actually executes under* — those rows really
     are skipped — but it moves with load; like the engine's idle-slot
     caveat, calibrate (and set ``maybe_recalibrate`` thresholds) from a

@@ -136,11 +136,22 @@ def decode_step(p: Params, cfg: ArchConfig, tokens: jax.Array, state: Params,
     positions (see ``attention.decode_step``).  ``commit`` (B,) bool masks
     which rows commit state (None: every row; see ``masked_decode_step``).
     """
+    logits, state, _ = _decode_step_counted(p, cfg, tokens, state, pos,
+                                            commit)
+    return logits, state
+
+
+def _decode_step_counted(p: Params, cfg: ArchConfig, tokens: jax.Array,
+                         state: Params, pos: jax.Array,
+                         commit: Optional[jax.Array] = None):
+    """``decode_step`` that also returns the MoE routing counts of the
+    committing rows (``transformer.decode_stack``; None for stacks
+    without experts)."""
     x = embed(cfg, p["embed"], tokens)
-    x, state = transformer.decode_stack(p["stack"], cfg, x, state, pos,
-                                        commit)
+    x, state, counts = transformer.decode_stack(p["stack"], cfg, x, state,
+                                                pos, commit)
     x = apply_norm(p["final_norm"], cfg, x)
-    return logits_head(cfg, head_matrix(p, cfg), x), state
+    return logits_head(cfg, head_matrix(p, cfg), x), state, counts
 
 
 def _batch_mask(mask: jax.Array, leaf: jax.Array) -> jax.Array:
@@ -164,13 +175,23 @@ def masked_decode_step(p: Params, cfg: ArchConfig, tokens: jax.Array,
     (``transformer.decode_stack``): each layer writes every row's K/V slot
     into the stacked cache, attends, and puts back the slot of each
     inactive row (so filler rows compute exactly what they did under a
-    whole-state select, which batch-coupled MoE routing sees); recurrent
-    leaves (SSM, RG-LRU) are selected per row on each layer's slice, and
-    read-only leaves (the encoder-decoder ``memory``) pass through.  No
-    step computes a whole new state to select from.
+    whole-state select); recurrent leaves (SSM, RG-LRU) are selected per
+    row on each layer's slice, and read-only leaves (the encoder-decoder
+    ``memory``) pass through.  No step computes a whole new state to
+    select from.
     """
+    logits, state, _ = _masked_decode_step_counted(p, cfg, tokens, state,
+                                                   pos, active)
+    return logits, state
+
+
+def _masked_decode_step_counted(p: Params, cfg: ArchConfig,
+                                tokens: jax.Array, state: Params,
+                                pos: jax.Array, active: jax.Array):
+    """``masked_decode_step`` plus the MoE routing counts of the
+    ``active`` rows (None for stacks without experts)."""
     with ops.active_rows(active):
-        return decode_step(p, cfg, tokens, state, pos, active)
+        return _decode_step_counted(p, cfg, tokens, state, pos, active)
 
 
 def sample_tokens(logits: jax.Array, temp: jax.Array, top_k: jax.Array,
@@ -220,8 +241,8 @@ def decode_many(p: Params, cfg: ArchConfig, tokens: jax.Array, state: Params,
                 top_k: Optional[jax.Array] = None,
                 seeds: Optional[jax.Array] = None,
                 nan_guard: bool = False,
-                ) -> Tuple[jax.Array, Params, jax.Array, jax.Array,
-                           jax.Array]:
+                moe_counts: bool = False,
+                ) -> Tuple[jax.Array, ...]:
     """Fused multi-token decode: ``n_steps`` decode steps in one
     ``lax.scan``, with on-device token selection feeding the next token.
 
@@ -274,6 +295,10 @@ def decode_many(p: Params, cfg: ArchConfig, tokens: jax.Array, state: Params,
     commits state, and the host simply truncates it to zero tokens.
     Speculation can waste device steps on such rows, but never corrupts
     a stream (see ``repro.serve.engine`` async dispatch).
+
+    ``moe_counts`` appends a sixth result: the MoE routing counts of the
+    active rows summed over the block's steps (see ``moe.decode_moe``;
+    None for stacks without experts).
     """
     live = live.astype(bool)
     b = tokens.shape[0]
@@ -283,10 +308,13 @@ def decode_many(p: Params, cfg: ArchConfig, tokens: jax.Array, state: Params,
     sample = temp is not None
 
     def step(carry, _):
-        tok, st, ps, rm = carry
+        tok, st, ps, rm, cnt = carry
         active = live & (rm > 0)
         feed = jnp.where(active, tok, 0).astype(jnp.int32)[:, None]
-        logits, st = masked_decode_step(p, cfg, feed, st, ps, active)
+        logits, st, c = _masked_decode_step_counted(p, cfg, feed, st, ps,
+                                                    active)
+        if cnt is not None:
+            cnt = cnt + c
         lg = logits[:, 0, :]
         if sample:
             nxt = sample_tokens(lg, temp, top_k, seeds, ps)
@@ -307,12 +335,23 @@ def decode_many(p: Params, cfg: ArchConfig, tokens: jax.Array, state: Params,
             rm = jnp.where(active, jnp.where(nxt == eos, 0, rm - 1), rm)
             tok = jnp.where(active, nxt, tok)
             ps = jnp.where(active, ps + 1, ps)
-        return (tok, st, ps, rm), emit
+        return (tok, st, ps, rm, cnt), emit
 
-    (tok, state, pos, rem), toks = maybe_unrolled_scan(
+    (tok, state, pos, rem, cnt), toks = maybe_unrolled_scan(
         step, (tokens.astype(jnp.int32), state, pos.astype(jnp.int32),
-               rem.astype(jnp.int32)), None, length=n_steps)
+               rem.astype(jnp.int32), _counts_init(cfg, moe_counts)),
+        None, length=n_steps)
+    if moe_counts:
+        return toks, state, tok, pos, rem, cnt
     return toks, state, tok, pos, rem
+
+
+def _counts_init(cfg: ArchConfig, on: bool) -> Optional[jax.Array]:
+    """Zero MoE routing counts ((1 + experts_held,) int32) where ``on``
+    and the stack has experts, else None."""
+    if not (on and cfg.moe.enabled):
+        return None
+    return jnp.zeros((1 + cfg.moe.experts_held,), jnp.int32)
 
 
 def verify_window(p: Params, cfg: ArchConfig, tokens: jax.Array,
@@ -391,14 +430,11 @@ def verify_block(p_full: Params, p_draft: Params, cfg: ArchConfig,
     ``masked_decode_step`` with commits gated on the still-matching mask,
     leaving the state exactly the accepted prefix's.
 
-    The sequential scorer's exactness claim holds for **row-decoupled**
-    families only: capacity-bounded MoE routing competes for expert slots
-    across the whole batch (`moe.py`), so a row going inactive after its
-    rejection point changes *other* rows' capacity outcomes relative to
-    the lockstep oracle — no per-row early-exit scheme can be exact
-    there.  That, plus the fact that k+1 sequential full-plan steps save
-    nothing over plain decode, is why ``ServeEngine`` gates speculation
-    to windowed-exact families and serves everything else plain blocks.
+    The sequential scorer is exact for every family whose rows are
+    decoupled (the served MoE layer is: ``moe.decode_moe`` routes each
+    row alone), but k+1 sequential full-plan steps save nothing over
+    plain decode, which is why ``ServeEngine`` gates speculation to
+    windowed-exact families and serves everything else plain blocks.
 
     ``nan_guard`` quarantines rows whose *verify-tier* logits go
     non-finite, exactly as in ``decode_many``: the row emits
@@ -492,7 +528,7 @@ def _reset_row(state: Params, row: jax.Array, reset: jax.Array) -> Params:
 def prefill_into_slot(p: Params, cfg: ArchConfig, tokens: jax.Array,
                       valid: jax.Array, slot: jax.Array, state: Params,
                       slot_pos: jax.Array, start: jax.Array = 0,
-                      reset: jax.Array = True) -> Params:
+                      reset: jax.Array = True, *, moe_counts: bool = False):
     """Feed one admitted prompt (or one *chunk* of it) into one decode-state
     slot in a single fused pass — uniform across dense / MoE / SSM / hybrid
     state families.
@@ -530,18 +566,24 @@ def prefill_into_slot(p: Params, cfg: ArchConfig, tokens: jax.Array,
     state = _reset_row(state, slot, reset)
     start = jnp.asarray(start, jnp.int32)
 
-    def step(st, inp):
+    def step(carry, inp):
+        st, cnt = carry
         t, tok, ok = inp
         merge = onehot & ok
         feed = jnp.where(merge, tok, 0).astype(jnp.int32)[:, None]
         ps = jnp.where(onehot, start + t, slot_pos).astype(jnp.int32)
-        _, st = masked_decode_step(p, cfg, feed, st, ps, merge)
-        return st, None
+        _, st, c = _masked_decode_step_counted(p, cfg, feed, st, ps, merge)
+        if cnt is not None:
+            cnt = cnt + c
+        return (st, cnt), None
 
     n = tokens.shape[0]
-    state, _ = maybe_unrolled_scan(
-        step, state, (jnp.arange(n, dtype=jnp.int32),
-                      tokens.astype(jnp.int32), valid.astype(bool)))
+    (state, cnt), _ = maybe_unrolled_scan(
+        step, (state, _counts_init(cfg, moe_counts)),
+        (jnp.arange(n, dtype=jnp.int32), tokens.astype(jnp.int32),
+         valid.astype(bool)))
+    if moe_counts:
+        return state, cnt
     return state
 
 

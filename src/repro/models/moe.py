@@ -1,35 +1,48 @@
-"""Mixture-of-Experts: routed top-k + shared experts, EP-sharded.
+"""Mixture-of-Experts: routed top-k + shared experts.
 
-Dispatch is capacity-bounded and *sort-based* (no (T, E, C) one-hot
-tensors — those are O(T·E·C) and unlowerable at production shapes).  The
-router bitmap plays the CSB role of FlexNN's two-sided sparsity logic: only
-"non-zero" (routed) token×expert pairs are fetched and computed
-(DESIGN.md §5).
+Routing: ``p = softmax(x W_r)`` over the ``n_experts`` routed experts in
+float32, the top ``top_k`` of ``p`` as gates, renormalised to sum to 1
+unless ``MoEConfig.norm_topk_prob`` is False (DeepSeek-MoE keeps the raw
+probabilities); ``y = sum_{e in top-k} p_e FFN_e(x) + FFN_shared(x)``.
 
-Three execution paths, selected by mesh context:
+A layer may hold only a share of the routed experts
+(``MoEConfig.experts_held`` from ``expert_offset``): one chip's share of an
+expert-parallel deployment.  It routes over all ``n_experts`` and adds
+only its own experts' part; the shared experts are held whole.
 
-  * **oracle** (``apply_moe_gshard``): the classic GShard one-hot einsum
-    dispatch.  O(T·E·C) — smoke scale only; semantic reference for tests.
-  * **local sort-based** (``_apply_moe_local``): argsort tokens by expert,
-    gather into a capacity-padded (E, C, D) buffer, batched expert matmuls,
-    scatter-add combine.  Used without a mesh and for decode-scale T.
-    Expert weights stay EP-sharded (E → "model"); XLA turns the gathers
-    into local slices.
-Decode note (fused serving): the MoE layer is state-free — only the
-attention caches thread through the ``model.decode_many`` scan carry — but
-routing is *batch-coupled*: capacity slots are competed for across all
-decode rows, including the token-0 filler rows of idle slots.  The fused
-block and the per-token engine path therefore feed bit-identical batch
-contents per step (same filler, same live masking), which is what keeps
-the fused MoE stream token-for-token equal to the oracle.
+Two kinds of path:
 
-  * **expert-parallel shard_map** (``_apply_moe_ep``): the production path.
-    Tokens enter sequence-sharded over the EP axis (SP), each device
-    routes its local tokens, buckets them by destination shard, exchanges
-    via ``all_to_all``, computes its local experts, and returns outputs via
-    the reverse ``all_to_all`` — the standard DeepSpeed-MoE/GShard EP
-    pipeline, here as an explicit collective schedule (the FlexTree
-    "choose your combine" idea applied to expert dispatch).
+  * **serving** (``decode_moe``): dropless and per-token, for the decode
+    step and the token-serial prefill.  Every held expert runs on every
+    row and each row keeps the outputs of the held experts among its
+    top-k, weighted by their gates: a row's output depends on its own
+    hidden state alone, never on its batch neighbours or on the filler
+    rows of idle slots.  At serving batch sizes (a few rows) this exact
+    form reads each held expert's weights once a step, which is what a
+    grouped kernel would read too.  Returns on-device routing counts for
+    the rows that commit (``ServeEngine`` sums them into its counters).
+  * **forward / training** (``apply_moe``): capacity-bounded and
+    *sort-based* (no (T, E, C) one-hot tensors -- those are O(T*E*C) and
+    unlowerable at production shapes).  The router bitmap plays the CSB
+    role of FlexNN's two-sided sparsity logic: only routed token x expert
+    pairs are fetched and computed (DESIGN.md §5).  Tokens over an
+    expert's capacity are dropped, so a token's output depends on the
+    other tokens of the call.  It needs every routed expert held.
+
+    - oracle (``apply_moe_gshard``): the classic GShard one-hot einsum
+      dispatch.  O(T*E*C) -- smoke scale only; semantic reference for tests.
+    - local sort-based (``_apply_moe_local``): argsort tokens by expert,
+      gather into a capacity-padded (E, C, D) buffer, batched expert
+      matmuls, scatter-add combine.  Used without a mesh.  Expert weights
+      stay EP-sharded (E -> "model"); XLA turns the gathers into local
+      slices.
+    - expert-parallel shard_map (``_apply_moe_ep``): tokens enter
+      sequence-sharded over the EP axis (SP), each device routes its local
+      tokens, buckets them by destination shard, exchanges via
+      ``all_to_all``, computes its local experts, and returns outputs via
+      the reverse ``all_to_all`` -- the standard DeepSpeed-MoE/GShard EP
+      pipeline, here as an explicit collective schedule (the FlexTree
+      "choose your combine" idea applied to expert dispatch).
 """
 from __future__ import annotations
 
@@ -60,19 +73,22 @@ def _dense_w(w):
 
 
 def init_moe(cfg: ArchConfig, rng, dtype=jnp.bfloat16) -> Params:
+    """The router over all ``n_experts``, the ``experts_held`` routed
+    experts of this share and the shared experts."""
     d = cfg.d_model
     m = cfg.moe
+    e, f = m.experts_held, m.expert_d_ff
     ks = jax.random.split(rng, 5)
-    s_in, s_ff = d ** -0.5, m.expert_d_ff ** -0.5
+    s_in, s_ff = d ** -0.5, f ** -0.5
     p = {
         "router": (jax.random.normal(ks[0], (d, m.n_experts)) * s_in
                    ).astype(jnp.float32),
-        "experts_in": (jax.random.normal(ks[1], (m.n_experts, d, m.expert_d_ff))
-                       * s_in).astype(dtype),
-        "experts_gate": (jax.random.normal(ks[2], (m.n_experts, d, m.expert_d_ff))
-                         * s_in).astype(dtype),
-        "experts_out": (jax.random.normal(ks[3], (m.n_experts, m.expert_d_ff, d))
-                        * s_ff).astype(dtype),
+        "experts_in": (jax.random.normal(ks[1], (e, d, f)) * s_in
+                       ).astype(dtype),
+        "experts_gate": (jax.random.normal(ks[2], (e, d, f)) * s_in
+                         ).astype(dtype),
+        "experts_out": (jax.random.normal(ks[3], (e, f, d)) * s_ff
+                        ).astype(dtype),
     }
     if m.n_shared:
         f = m.expert_d_ff * m.n_shared
@@ -89,20 +105,29 @@ def init_moe(cfg: ArchConfig, rng, dtype=jnp.bfloat16) -> Params:
 # Routing + sort-based dispatch primitives
 # ---------------------------------------------------------------------------
 
-def _route(router: jax.Array, xt: jax.Array, k: int
+def _top_k_gates(probs: jax.Array, k: int, norm: bool
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """probs (T, E) -> (gates (T, k), idx (T, k) i32): the top k, each
+    row's gates renormalised to sum to 1 where ``norm``."""
+    gate_vals, gate_idx = jax.lax.top_k(probs, k)
+    if norm:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+    return gate_vals, gate_idx
+
+
+def _route(router: jax.Array, xt: jax.Array, k: int, norm: bool = True
            ) -> Tuple[jax.Array, jax.Array]:
-    """xt (T, D) -> (gates (T, k) f32 renormalized, idx (T, k) i32).
+    """xt (T, D) -> (gates (T, k) f32, idx (T, k) i32).
 
     The router matmul is a planned dispatch site (``moe.router``) like any
     other — under a sparse descriptor it runs the block-sparse path, which
     skips only true-zero blocks and stays numerically identical to dense.
     """
     logits = ops.flex_matmul(xt.astype(jnp.float32), router,
-                             site="moe.router")
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-    return gate_vals, gate_idx
+                             site="moe.router",
+                             precision=jax.lax.Precision.HIGHEST)
+    return _top_k_gates(jax.nn.softmax(logits, axis=-1), k, norm)
 
 
 def _dispatch_indices(fid: jax.Array, n_bins: int, capacity: int
@@ -168,7 +193,7 @@ def _capacity(tokens: int, k: int, n_bins: int, cf: float) -> int:
 def _apply_moe_local(p: Params, cfg: ArchConfig, xt: jax.Array) -> jax.Array:
     t, d = xt.shape
     m = cfg.moe
-    gates, gate_idx = _route(p["router"], xt, m.top_k)
+    gates, gate_idx = _route(p["router"], xt, m.top_k, m.norm_topk_prob)
     f = t * m.top_k
     fid = gate_idx.reshape(f)
     cap = _capacity(t, m.top_k, m.n_experts, m.capacity_factor)
@@ -204,7 +229,7 @@ def _apply_moe_ep(p: Params, cfg: ArchConfig, x: jax.Array, rules
         bl, sl, _ = xb.shape                     # local (b/dp, s/ep, d)
         t_l = bl * sl
         xt = xb.reshape(t_l, d)
-        gates, gate_idx = _route(router, xt, m.top_k)
+        gates, gate_idx = _route(router, xt, m.top_k, m.norm_topk_prob)
         f = t_l * m.top_k
         fid = gate_idx.reshape(f)
         gflat = gates.reshape(f)
@@ -274,11 +299,32 @@ def _ep_applicable(cfg: ArchConfig, x: jax.Array, rules) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Public entry
+# Public entries
 # ---------------------------------------------------------------------------
+
+def _shared_ffn(sp: Params, xt: jax.Array) -> jax.Array:
+    """The shared experts, one SwiGLU over every row: (T, D) -> (T, D).
+    Ordinary dispatch sites (``moe.shared_*``)."""
+    hs = (jax.nn.silu(ops.flex_matmul(xt, sp["w_gate"],
+                                      site="moe.shared_gate"))
+          * ops.flex_matmul(xt, sp["w_in"], site="moe.shared_in"))
+    hs = shard(hs, "batch", "ffn")
+    return ops.flex_matmul(hs, sp["w_out"], site="moe.shared_out")
+
+
+def _require_all_experts(cfg: ArchConfig) -> None:
+    m = cfg.moe
+    if m.partial:
+        raise ValueError(
+            f"{cfg.name}: the capacity-bounded MoE path needs every routed "
+            f"expert, but this layer holds {m.experts_held} of "
+            f"{m.n_experts}; a chip's share of the experts is served "
+            f"through decode_moe only")
+
 
 def apply_moe(p: Params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     """x (B, S, D) -> (B, S, D): routed experts + shared experts."""
+    _require_all_experts(cfg)
     b, s, d = x.shape
     rules = current_rules()
     if _ep_applicable(cfg, x, rules):
@@ -288,26 +334,62 @@ def apply_moe(p: Params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
 
     y = shard(y, "batch", "seq", "embed")       # pin the residual stream (SP-aware)
     if "shared" in p:
-        # shared experts are ordinary dispatch sites (moe.shared_*)
-        sp = p["shared"]
-        xt = x.reshape(b * s, d)
-        hs = (jax.nn.silu(ops.flex_matmul(xt, sp["w_gate"],
-                                          site="moe.shared_gate"))
-              * ops.flex_matmul(xt, sp["w_in"], site="moe.shared_in"))
-        hs = shard(hs, "batch", "ffn")
-        ys = shard(ops.flex_matmul(hs, sp["w_out"], site="moe.shared_out"
-                                   ).reshape(b, s, d), "batch", None,
-                   "embed")
-        y = y + ys
+        ys = _shared_ffn(p["shared"], x.reshape(b * s, d))
+        y = y + shard(ys.reshape(b, s, d), "batch", None, "embed")
     return y
+
+
+def decode_moe(p: Params, cfg: ArchConfig, x: jax.Array,
+               rows: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """Dropless, per-token MoE layer for serving: x (B, S, D) -> (y (B, S,
+    D), counts (1 + experts_held,) int32).
+
+    Routes every token over all ``n_experts`` (float32 softmax, top-k,
+    gates as ``norm_topk_prob`` says) and adds ``sum p_e FFN_e(x)`` over
+    the held experts among its top-k, plus the shared experts.  Every held
+    expert runs on every row, weighted by its gate or by zero, so a row's
+    output depends only on its own hidden state.
+
+    ``counts`` are the routing picks of the rows in ``rows`` (B,) bool
+    (None: every row): ``counts[0]`` the picks over all ``n_experts``
+    (``top_k`` a token), ``counts[1 + j]`` the picks of held expert j.
+    """
+    b, s, d = x.shape
+    m = cfg.moe
+    t = b * s
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe.router"):
+        gates, idx = _route(p["router"], xt, m.top_k, m.norm_topk_prob)
+        # one_hot of an out-of-range index is all zeros: picks of experts
+        # held elsewhere add nothing here
+        pick = jax.nn.one_hot(idx - m.expert_offset, m.experts_held,
+                              dtype=jnp.int32)              # (T, k, E_h)
+        # elementwise products and sums, not dots: a float32 dot may
+        # round its operands to bf16 on the TPU
+        weight = (gates[..., None] * pick).sum(1).T         # (E_h, T) f32
+        live = (jnp.ones((t,), bool) if rows is None
+                else jnp.repeat(rows.astype(bool), s))
+        counts = jnp.concatenate([
+            (live.sum() * m.top_k)[None],
+            (pick * live[:, None, None]).sum((0, 1))]).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        xe = shard(jnp.broadcast_to(xt[None], (m.experts_held, t, d)),
+                   "expert", None, None)
+        ye = shard(_expert_ffn(xe, p), "expert", None, None)  # (E_h, T, D)
+        y = (ye.astype(jnp.float32) * weight[..., None]).sum(0)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + _shared_ffn(p["shared"], xt).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(b, s, d), counts
 
 
 # ---------------------------------------------------------------------------
 # GShard one-hot oracle (smoke scale; semantic reference for tests)
 # ---------------------------------------------------------------------------
 
-def _top_k_gating(logits: jax.Array, k: int, capacity: int
-                  ) -> Tuple[jax.Array, jax.Array]:
+def _top_k_gating(logits: jax.Array, k: int, capacity: int,
+                  norm: bool = True) -> Tuple[jax.Array, jax.Array]:
     """logits (T, E) -> (dispatch (T, E, C), combine (T, E, C)).
 
     First-come capacity policy over the *flat (token, slot)* order — token-
@@ -315,8 +397,7 @@ def _top_k_gating(logits: jax.Array, k: int, capacity: int
     """
     t, e = logits.shape
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)           # (T, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, gate_idx = _top_k_gates(probs, k, norm)      # (T, k)
 
     # flat assignment order (t-major, slot-minor), position within expert
     onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)   # (T, k, E)
@@ -339,6 +420,7 @@ def apply_moe_gshard(p: Params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     matmuls, dense weights) so it stays a semantic reference the sparse
     paths are tested *against*.
     """
+    _require_all_experts(cfg)
     b, s, d = x.shape
     m = cfg.moe
     t = b * s
@@ -346,7 +428,8 @@ def apply_moe_gshard(p: Params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     capacity = _capacity(t, m.top_k, m.n_experts, m.capacity_factor)
 
     logits = (xt.astype(jnp.float32) @ _dense_w(p["router"]))    # (T, E)
-    dispatch, combine = _top_k_gating(logits, m.top_k, capacity)
+    dispatch, combine = _top_k_gating(logits, m.top_k, capacity,
+                                      m.norm_topk_prob)
 
     xe = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), xt)
     ye = _expert_ffn_dense(xe, p)

@@ -106,12 +106,15 @@ def apply_moe_layer(p: Params, cfg: ArchConfig, x: jax.Array, *,
 
 def decode_moe_layer(p: Params, cfg: ArchConfig, x, cache, pos, layer, *,
                      commit=None):
+    """One MoE layer's decode step; also returns the routing counts of the
+    committing rows (``moe.decode_moe``)."""
     h = apply_norm(p["ln1"], cfg, x)
     o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos, layer,
                                      commit=commit)
     x = x + o
     h = apply_norm(p["ln2"], cfg, x)
-    return x + moe_mod.apply_moe(p["moe"], cfg, h), cache
+    y, counts = moe_mod.decode_moe(p["moe"], cfg, h, rows=commit)
+    return x + y, cache, counts
 
 
 def init_ssm_layer(cfg: ArchConfig, rng, dtype=jnp.bfloat16) -> Params:
@@ -421,8 +424,11 @@ def _scan_layers(step, x: jax.Array, params: Params, kv: Params, xs=None):
 
 def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
                  pos: jax.Array, commit: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, Params]:
-    """One-token step through the full stack.  x (B,1,D).
+                 ) -> Tuple[jax.Array, Params, Optional[jax.Array]]:
+    """One-token step through the full stack.  x (B,1,D).  Returns the new
+    hidden state, the new decode state and, for MoE stacks, the routing
+    counts of the committing rows summed over the MoE layers
+    ((1 + experts_held,) int32, see ``moe.decode_moe``; None otherwise).
 
     ``pos`` is a scalar or a (B,) per-sequence position vector — it flows
     unchanged to ``attention.decode_step`` (the only consumer); recurrent
@@ -454,7 +460,7 @@ def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
             return h + apply_mlp(lp["mlp"], cfg, y), kv, None
         x_out, kv, _ = _scan_layers(dec_step, x, p["decoder"], state["self"],
                                     state["memory"])
-        return x_out, {"self": kv, "memory": state["memory"]}
+        return x_out, {"self": kv, "memory": state["memory"]}, None
 
     def rec_step(decode_layer):
         def step(lp, h, kv, st, layer):
@@ -465,7 +471,7 @@ def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
     if cfg.ssm.enabled:
         x_out, _, st = _scan_layers(rec_step(decode_ssm_layer), x,
                                     p["layers"], {}, state["layers"])
-        return x_out, {"layers": st}
+        return x_out, {"layers": st}, None
 
     if cfg.rglru.enabled:
         attn = {f"b{i}_{kind}" for i, kind in enumerate(cfg.rglru.block_pattern)
@@ -484,7 +490,7 @@ def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
             x_out, _, new["trailing"] = _scan_layers(
                 rec_step(decode_rec_layer), x_out, p["trailing"], {},
                 state["trailing"])
-        return x_out, new
+        return x_out, new, None
 
     def kv_step(decode_layer, **kw):
         def step(lp, h, kv, _, layer):
@@ -500,13 +506,17 @@ def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
             x_out, new["dense_layers"], _ = _scan_layers(
                 kv_step(decode_dense_layer), x_out, p["dense_layers"],
                 state["dense_layers"])
-        x_out, new["layers"], _ = _scan_layers(
-            kv_step(decode_moe_layer), x_out, p["layers"], state["layers"])
-        return x_out, new
+
+        def moe_step(lp, h, kv, _, layer):
+            return decode_moe_layer(lp, cfg, h, kv, pos, layer,
+                                    commit=commit)
+        x_out, new["layers"], counts = _scan_layers(
+            moe_step, x_out, p["layers"], state["layers"])
+        return x_out, new, counts.sum(0)
 
     x_out, kv, _ = _scan_layers(kv_step(decode_dense_layer, window=cfg.window),
                                 x, p["layers"], state["layers"])
-    return x_out, {"layers": kv}
+    return x_out, {"layers": kv}, None
 
 
 def decode_stack_window(p: Params, cfg: ArchConfig, x: jax.Array,
@@ -516,12 +526,11 @@ def decode_stack_window(p: Params, cfg: ArchConfig, x: jax.Array,
     verify scorer (``model.verify_window``).  x (B, W, D); ``pos`` (B,) the
     position of each row's first window token.
 
-    Dense full-cache stacks only: MoE is deliberately excluded (its
-    expert-capacity dispatch is computed over the flattened (B·W) token
-    batch, so window tokens would *compete* for capacity with each other —
-    different drops than W sequential steps → inexact scoring), as are the
-    recurrent families (SSM / RG-LRU carry state token-to-token; a batched
-    window cannot reproduce the k-th step's carry without scanning).
+    Dense full-cache stacks only: MoE is excluded (this scorer has no
+    expert layer, and ``ServeEngine`` keeps speculation off for MoE), as
+    are the recurrent families (SSM / RG-LRU carry state token-to-token;
+    a batched window cannot reproduce the k-th step's carry without
+    scanning).
     Those families verify with the sequential scorer in
     ``model.verify_block`` instead.
     """

@@ -89,6 +89,17 @@ def _is_matmul_leaf(path: str, leaf) -> bool:
     return bool(_MATMUL_LEAF.match(path)) and getattr(leaf, "ndim", 0) >= 2
 
 
+@functools.lru_cache(maxsize=None)
+def _stacked_quantizer(n_lead: int):
+    """``quantize_weight`` over ``n_lead`` leading stack axes, compiled as
+    one pass: eager ops would hold several float32 copies of a large
+    expert stack at once."""
+    q = quantize_weight
+    for _ in range(n_lead):
+        q = jax.vmap(q)
+    return jax.jit(q)
+
+
 def quantize_params(params, *, tie_embeddings: bool = False
                     ) -> Tuple[Dict, Dict]:
     """Pytree → (same-structure tree with QuantizedLinear at matmul leaves,
@@ -114,10 +125,7 @@ def quantize_params(params, *, tie_embeddings: bool = False
             leaf_kn = jnp.swapaxes(leaf, -1, -2)
         else:
             leaf_kn = leaf
-        q2 = quantize_weight
-        for _ in range(leaf_kn.ndim - 2):
-            q2 = jax.vmap(q2)
-        out = q2(leaf_kn)
+        out = _stacked_quantizer(leaf_kn.ndim - 2)(leaf_kn)
         stats["n_quantized"] += 1
         stats["original_bytes"] += leaf.size * leaf.dtype.itemsize
         stats["quantized_bytes"] += out.q.size + out.scale.size * 4

@@ -565,6 +565,16 @@ class ServeEngine:
         self.counters.update(demotions=0, admitted=0, prefill_segments=0,
                              prefill_tokens=0, prefill_steps=0,
                              decode_blocks=0, decode_steps=0, syncs=0)
+        # MoE stacks: routing picks of the rows that commit, counted on the
+        # device by every decode block and prefill segment
+        # (``moe.decode_moe``): over all routed experts, and per held
+        # expert.  A dispatch's counts are folded in once the device has
+        # them (``_fold_moe_counts``), so health() never waits on it.
+        self._moe_pending: List[jax.Array] = []
+        if cfg.moe.enabled:
+            self.counters.update(
+                moe_assignments=0,
+                moe_assignments_held=[0] * cfg.moe.experts_held)
         # terminal uid -> (status, credited output tokens, lifecycle
         # stamps), bounded so status(), results() and request_times()
         # outlive slot recycling without unbounded growth
@@ -641,8 +651,8 @@ class ServeEngine:
         # speculative verify runs the whole k+1 window in ONE batched
         # forward only for families where that is bitwise-equal to k+1
         # sequential steps: plain dense-attention full-cache stacks.
-        # Everything else (MoE capacity competes across the batch and the
-        # window, recurrent state, sliding windows) has no exact-and-
+        # Everything else (MoE, whose per-token expert layer the window
+        # scorer lacks; recurrent state; sliding windows) has no exact-and-
         # cheaper parallel scorer, so ``_spec_k_for`` gates speculation
         # OFF for those families and they serve plain decode blocks —
         # ``speculate_k`` is then a no-op, not an approximation.
@@ -728,16 +738,22 @@ class ServeEngine:
             # cache on both paths, and popcounts see live rows only
             return model_lib.masked_decode_step(p, cfg, t, s, pos, live)
 
+        # both return the MoE routing counts last (None without experts)
+        moe = cfg.moe.enabled
+
         def serve_decode_many(p, s, toks, pos, live, rem, temp, top_k,
                               seeds, n_steps):
-            return model_lib.decode_many(p, cfg, toks, s, pos, live, n_steps,
-                                         rem=rem, eos_id=eos_id, temp=temp,
-                                         top_k=top_k, seeds=seeds,
-                                         nan_guard=nan_guard)
+            out = model_lib.decode_many(p, cfg, toks, s, pos, live, n_steps,
+                                        rem=rem, eos_id=eos_id, temp=temp,
+                                        top_k=top_k, seeds=seeds,
+                                        nan_guard=nan_guard, moe_counts=moe)
+            return out if moe else (*out, None)
 
         def serve_prefill(p, s, toks, valid, slot, slot_pos, start, reset):
-            return model_lib.prefill_into_slot(p, cfg, toks, valid, slot, s,
-                                               slot_pos, start, reset)
+            out = model_lib.prefill_into_slot(p, cfg, toks, valid, slot, s,
+                                              slot_pos, start, reset,
+                                              moe_counts=moe)
+            return out if moe else (out, None)
 
         def serve_verify(p_full, p_draft, s, toks, pos, live, rem, temp,
                          top_k, seeds, k, windowed):
@@ -806,7 +822,7 @@ class ServeEngine:
         cap = _next_pow2(self.admission.chunk_cap(self) or self.max_seq)
         p = 1
         while p <= cap:
-            self.state = self._prefill(
+            self.state, _ = self._prefill(
                 self._exec_params, self.state, np.zeros((p,), np.int32),
                 np.zeros((p,), bool), np.int32(0), zero, np.int32(1),
                 False)
@@ -1155,13 +1171,17 @@ class ServeEngine:
         speculation state, per-request lifecycle statuses for everything
         the engine currently tracks (queued + slot-bound), the lifetime
         ``counters`` (terminal statuses, demotions, admissions, prefill
-        segments / tokens / steps, decode blocks / steps, syncs) and the
-        speculative-decoding stats.
+        segments / tokens / steps, decode blocks / steps, syncs; on an MoE
+        engine the routing picks ``moe_assignments`` and, one per held
+        expert, ``moe_assignments_held``) and the speculative-decoding
+        stats.
 
         Snapshot semantics — no flush, no device sync: figures reflect
-        accounting up to the last synced block (``flush()`` first for
+        accounting up to the last synced block, and MoE counts up to the
+        last dispatch the device has finished (``flush()`` first for
         exact-at-this-instant numbers).  Cheap enough to poll every tick.
         """
+        self._fold_moe_counts()
         live = self._live()
         prefilling = self._prefilling()
         requests = {r.uid: r.status for r in self.queue}
@@ -1177,7 +1197,8 @@ class ServeEngine:
             "inflight_speculative": sum(1 for b in self._inflight
                                         if b.spec_k),
             "requests": requests,
-            "counters": dict(self.counters),
+            "counters": {k: list(v) if isinstance(v, list) else v
+                         for k, v in self.counters.items()},
             "spec": dict(self.spec_stats),
             "tok_ema_s": self._tok_ema,
         }
@@ -1221,10 +1242,11 @@ class ServeEngine:
         self.counters["prefill_steps"] += padded
         with TraceAnnotation("serve.prefill.dispatch", uid=s.req.uid,
                              tokens=len(seg), steps=padded):
-            self.state = self._prefill(self._exec_params, self.state,
-                                       toks, valid, np.int32(i),
-                                       self._slot_positions(),
-                                       np.int32(start), start == 0)
+            self.state, moe = self._prefill(self._exec_params, self.state,
+                                            toks, valid, np.int32(i),
+                                            self._slot_positions(),
+                                            np.int32(start), start == 0)
+        self._note_moe_counts(moe)
         s.prefill_cursor = start + len(seg)
         s.pos = s.prefill_cursor
         fed = s.prefill_cursor >= self._feed_len(s.req)
@@ -1505,8 +1527,7 @@ class ServeEngine:
         speculates (self-drafting under the full plan, the always-accept
         test mode).  Families without a windowed-exact parallel scorer
         (``_spec_windowed`` False) never speculate — the sequential
-        scorer saves nothing and batch-coupled MoE routing would drift
-        from the lockstep oracle."""
+        scorer saves nothing over plain decode."""
         if not self.speculate_k or not self._spec_windowed or t_block < 2:
             return 0
         n = len(self._tier_params)
@@ -1540,17 +1561,19 @@ class ServeEngine:
         self.counters["decode_steps"] += t_block
         with TraceAnnotation("serve.decode.dispatch", steps=t_block,
                              live=len(live)):
+            moe = None
             if spec_k:
                 block, self.state, dev_tok, dev_pos, dev_rem = self._verify(
                     self._tier_params[tier], self._tier_params[-1],
                     self.state, toks_in, pos_in, self._live_mask(live),
                     rem_in, temp, topk, seeds, spec_k, self._spec_windowed)
             else:
-                block, self.state, dev_tok, dev_pos, dev_rem = \
+                block, self.state, dev_tok, dev_pos, dev_rem, moe = \
                     self._decode_many(
                         self._tier_params[tier], self.state, toks_in,
                         pos_in, self._live_mask(live), rem_in, temp, topk,
                         seeds, t_block)
+        self._note_moe_counts(moe)
         key = self._live_key(live)
         self._carry = (key, dev_tok, dev_pos, dev_rem)
         self._inflight.append(_InflightBlock(key, list(live), t_block,
@@ -1587,6 +1610,7 @@ class ServeEngine:
             block = np.asarray(blk.block)
         with TraceAnnotation("serve.account"):
             credited = self._append_block(blk.live, block, blk.t_block)
+            self._fold_moe_counts()
         # service-rate EMA (seconds per credited token) between accounted
         # blocks — the deadline-pressure demotion trigger's estimate.  A
         # deterministic VirtualClock that never advances keeps this None/0,
@@ -1632,7 +1656,29 @@ class ServeEngine:
         out: Dict[int, List[int]] = {}
         while self._inflight:
             self._account_one(out)
+        self._fold_moe_counts(wait=True)
         return out
+
+    def _note_moe_counts(self, counts: Optional[jax.Array]):
+        """Park a dispatch's on-device MoE routing counts until they are
+        folded into ``counters``."""
+        if counts is not None:
+            self._moe_pending.append(counts)
+
+    def _fold_moe_counts(self, wait: bool = False):
+        """Add the parked MoE routing counts that the device has finished
+        (all of them where ``wait``) to ``counters``."""
+        keep = []
+        for c in self._moe_pending:
+            if not (wait or c.is_ready()):
+                keep.append(c)
+                continue
+            c = np.asarray(c)
+            self.counters["moe_assignments"] += int(c[0])
+            held = self.counters["moe_assignments_held"]
+            for j, n in enumerate(c[1:]):
+                held[j] += int(n)
+        self._moe_pending = keep
 
     def speculative_acceptance(self) -> float:
         """Lifetime draft acceptance rate: accepted drafts / proposed

@@ -90,8 +90,8 @@ def test_decode_matches_forward(arch):
 
     Tolerances: SSD's intra-chunk exp(Δcumsum) vs the stepwise exp-product
     drift ~0.2 % per layer in f32 (chunk=1 is bit-exact — verified in
-    test_ssd_chunk_sizes); MoE needs a capacity bump so forward-vs-decode
-    dispatch drops don't differ (capacity competition is per-call)."""
+    test_ssd_chunk_sizes); MoE needs a capacity bump so that the forward's
+    capacity-bounded dispatch drops nothing (the decode layer is dropless)."""
     cfg = get_smoke_config(arch)
     tol = dict(rtol=2e-3, atol=2e-3)
     if cfg.ssm.enabled:

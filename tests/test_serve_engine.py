@@ -135,8 +135,8 @@ def test_fused_matches_per_token_planned_sparse(cfg_and_params):
 
 
 def test_fused_matches_per_token_moe():
-    """MoE family: routing/capacity competition sees identical batch
-    contents per step on both paths (planned sparse dispatch included)."""
+    """MoE family: the dropless per-token expert layer gives the same
+    stream on both paths (planned sparse dispatch included)."""
     cfg = get_smoke_config("deepseek-moe-16b")
     params = model_lib.init_params(cfg, jax.random.PRNGKey(0),
                                    dtype=jnp.float32)

@@ -318,8 +318,8 @@ def test_speculative_streams_exact(family):
     _, oracle = _serve(cfg, params, ec, prompts, quantize=q, fused=False)
     assert spec_out == oracle
     if family == "moe":
-        # no windowed-exact scorer for batch-coupled MoE routing:
-        # speculation must be gated off, not approximated
+        # the windowed scorer has no expert layer: speculation must be
+        # gated off, not approximated
         assert not es._spec_windowed
         assert es.spec_stats["verify_blocks"] == 0
     else:

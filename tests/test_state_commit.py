@@ -119,8 +119,7 @@ def test_masked_decode_step_commits_active_rows_only(family, pos_kind,
     want_logits, want = _ref_masked_decode_step(params, cfg, tokens, state,
                                                 pos, active)
     _assert_rows(got, want, state, ~ACTIVE)
-    # every row, filler included, computes what it computed before:
-    # batch-coupled MoE routing sees the filler rows too
+    # every row, filler included, computes what it computed before
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
 
 

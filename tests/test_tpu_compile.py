@@ -214,3 +214,83 @@ def test_decode_state_commits_in_place(one_chip):
         assert producers <= writes | passes, (name, producers - writes - passes)
         header = hlo.splitlines()[0]        # HloModule ..., input_output_alias
         assert header.count("may-alias") >= 2, name     # k and v donated
+
+
+def _top_level_labels(hlo: str):
+    """``trace.op_label`` of every instruction outside fused computations:
+    the operations a device trace shows as events."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "benchmarks/chip/trace.py"
+    spec = importlib.util.spec_from_file_location("bench_trace", path)
+    trace = sys.modules[spec.name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace)
+    labels, fused = set(), False
+    for line in hlo.splitlines():
+        if line and not line[0].isspace():
+            fused = "fused" in line.split("(")[0]
+        elif not fused and " = " in line:
+            label = trace.op_label(line.strip().removeprefix("ROOT "))
+            if label is not None:
+                labels.add(label)
+    return labels
+
+
+def test_moe_share_decode_block_fits_one_chip(one_chip):
+    """The DeepSeek-MoE-16B chip share of the benchmark (28 layers, 8 of
+    64 routed experts held, 8 slots of 1024 positions, bf16) compiles as a
+    16-step decode block and a 128-token prefill segment for one v5e; the
+    arguments and temporaries of each fit its 16 GiB, and the expert
+    operations the ``expert_ms_per_step`` reader picks by shape are there:
+    the up/gate projection and the down projection fused with the gated
+    sum, one each."""
+    import importlib.util
+    import json
+    from pathlib import Path
+    from repro.configs.base import ArchConfig
+    from repro.models import model as model_lib
+    bench = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    spec = json.loads(
+        (bench / "configs" / "deepseek-moe-16b-ep8.json").read_text())
+    cfg = ArchConfig(**spec["model"])
+    n_slots, max_seq = spec["engine"]["n_slots"], spec["engine"]["max_seq"]
+    assert (cfg.n_layers, cfg.moe.experts_held, cfg.moe.n_experts) == \
+        (28, 8, 64)
+    rspec = importlib.util.spec_from_file_location(
+        "bench_expert_ms", bench / "metrics" / "expert_ms_per_step.py")
+    reader = importlib.util.module_from_spec(rspec)
+    rspec.loader.exec_module(reader)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _shape(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: model_lib.init_params(
+        cfg, jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda: model_lib.init_decode_state(
+        cfg, n_slots, max_seq)))
+    rows = _shape((n_slots,), jnp.int32, one_chip)
+    flags = _shape((n_slots,), jnp.bool_, one_chip)
+    scalar = _shape((), jnp.int32, one_chip)
+    decode = jax.jit(
+        lambda p, s, t, pos, live, rem: model_lib.decode_many(
+            p, cfg, t, s, pos, live, 16, rem=rem, moe_counts=True),
+        donate_argnums=(1,)).lower(params, state, rows, rows, flags, rows)
+    prefill = jax.jit(
+        lambda p, s, t, v, slot, sp, start, reset: model_lib.prefill_into_slot(
+            p, cfg, t, v, slot, s, sp, start, reset, moe_counts=True),
+        donate_argnums=(1,)).lower(
+            params, state, _shape((128,), jnp.int32, one_chip),
+            _shape((128,), jnp.bool_, one_chip), scalar, rows, scalar,
+            _shape((), jnp.bool_, one_chip))
+    for name, lowered in (("decode_many", decode), ("prefill", prefill)):
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert total < 16 * 2 ** 30, (name, total)
+        labels = _top_level_labels(compiled.as_text())
+        picked = reader.expert_labels(dict.fromkeys(labels, 0.0),
+                                      spec["model"], spec["engine"])
+        assert sorted(picked) == ["fusion bf16[8,8,1408]",
+                                  "fusion f32[8,2048]"], (name, picked)
