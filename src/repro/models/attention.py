@@ -320,6 +320,53 @@ def decode_step(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
     return out, cache
 
 
+def prefill_step(p: Params, cfg: ArchConfig, x: jax.Array, cache: Params,
+                 layer: jax.Array, row: jax.Array, start: jax.Array,
+                 valid: jax.Array) -> Tuple[jax.Array, Params]:
+    """W prompt tokens of one sequence through one layer.  x (1,W,D) holds
+    the tokens at positions ``start … start+W-1`` of batch row ``row``;
+    cache k/v are the whole stack's full-length (L,B,C,KVH·hd) buffers and
+    ``layer`` this layer's index.
+
+    The ``valid`` (W,) tokens' K/V are scattered into ``(layer, row,
+    start+i)`` in place; a padded token's index is out of range and the
+    scatter drops it, so it never overwrites a slot, clamped or not.  The
+    W queries then attend causally (slot ≤ own position) over that row's
+    layer slice, split into heads: ``dense_attention``, with the same
+    products and rounding points as the decode step's ``cache_attention``
+    and none of its zero blocks.  Other rows and layers are untouched.
+    """
+    _, w, _ = x.shape
+    hd = cfg.head_dim
+    q, k_new, v_new = _project_qkv(p, cfg, x)
+    posw = (jnp.asarray(start, jnp.int32)
+            + jnp.arange(w, dtype=jnp.int32))[None]                # (1, W)
+    qf = q.reshape(1, w, cfg.n_heads, hd)
+    qf = rope.apply_rope(qf, posw, kind=cfg.rope, theta=cfg.rope_theta)
+    q = qf.reshape(q.shape)
+    k_new = rope.apply_rope(k_new, posw, kind=cfg.rope, theta=cfg.rope_theta)
+
+    size = cache["k"].shape[2]
+    slots = jnp.where(valid, jnp.minimum(posw[0], size - 1), size)
+    news = {"k": k_new, "v": v_new}
+    cache = {name: cache[name].at[layer, row, slots].set(
+                 news[name].reshape(w, -1).astype(cache[name].dtype),
+                 mode="drop")
+             for name in news}
+
+    def row_heads(buf):                           # (1, C, KVH, hd)
+        one = jax.lax.dynamic_slice(buf, (layer, row, 0, 0),
+                                    (1, 1) + buf.shape[2:])
+        return one.reshape(1, size, cfg.n_kv_heads, hd)
+    mask = jnp.arange(size)[None, :] <= posw[0][:, None]            # (W, C)
+    o = dense_attention(q, row_heads(cache["k"]), row_heads(cache["v"]),
+                        mask[None, None, None])
+    cache = {name: shard(a, None, "cache_batch", "cache_seq", None)
+             for name, a in cache.items()}
+    o = o.reshape(1, w, cfg.n_heads * hd)
+    return ops.flex_matmul(o, p["wo"], site="attn.out"), cache
+
+
 def _read_rows(buf: jax.Array, layer: jax.Array, slot: jax.Array
                ) -> jax.Array:
     """Each row's (KVH·hd) value at its ``slot`` of the stacked cache

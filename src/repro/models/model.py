@@ -514,7 +514,14 @@ def verify_block(p_full: Params, p_draft: Params, cfg: ArchConfig,
 def _reset_row(state: Params, row: jax.Array, reset: jax.Array) -> Params:
     """Zero batch row ``row`` of every stacked state leaf (L, B, ...) where
     ``reset`` holds: one row slab per leaf, written in place with
-    ``dynamic_update_slice``."""
+    ``dynamic_update_slice``.
+
+    Admission resets the row: recurrent families (SSM / RG-LRU) carry
+    state across tokens, and the freed slot's old trajectory must not
+    bleed into the new request.  KV slots past the position are masked,
+    but a masked slot still enters the value sum with weight 0, and
+    0 * NaN is NaN: a row quarantined by ``nan_guard`` would poison its
+    slot's next request."""
     reset = jnp.asarray(reset, bool)
 
     def one(a):
@@ -535,34 +542,58 @@ def prefill_into_slot(p: Params, cfg: ArchConfig, tokens: jax.Array,
 
     ``tokens`` (P,) is the prompt feed segment (zero-padded to a static
     length), ``valid`` (P,) marks real positions, ``slot`` the batch row
-    being filled, ``slot_pos`` (B,) every slot's current position (the
-    other rows run as masked filler).  ``start`` is the sequence position
-    of the segment's first token — chunked prefill feeds
-    ``feed[c : c+chunk]`` with ``start = c`` so a long prompt admits across
-    several calls interleaved with decode blocks.  ``reset`` zero-resets
-    the admitted row before feeding (True on the whole-prompt path and on
-    chunk 0; later chunks must NOT re-reset the prefix they already wrote).
+    being filled, ``slot_pos`` (B,) every slot's current position.
+    ``start`` is the sequence position of the segment's first token —
+    chunked prefill feeds ``feed[c : c+chunk]`` with ``start = c`` so a
+    long prompt admits across several calls interleaved with decode
+    blocks.  ``reset`` zero-resets the admitted row before feeding (True on
+    the whole-prompt path and on chunk 0; later chunks must NOT re-reset
+    the prefix they already wrote).
 
-    Scans ``decode_step`` over the P positions with per-slot positions,
-    merging state updates **only at the admitted row on valid steps** —
-    live slots' rows are bit-untouched, and the zero-reset stops recurrent
-    state leaking from the slot's previous occupant.  Every per-layer state
-    leaf carries batch at axis 1: (L, B, ...).
+    Stacks whose decode state is only full-length KV caches
+    (``transformer.kv_state_only``: dense without a window, MoE) run the
+    segment as **one forward pass** of the admitted row's P tokens
+    (``transformer.prefill_stack``): each layer writes the valid tokens'
+    K/V into the row in place and attends them causally over it; padded
+    tokens write nothing and, under MoE, neither route nor count.  No
+    logits are computed.  Every other family (ring caches, SSM, RG-LRU,
+    encoder-decoder) carries state token to token and scans the
+    token-serial ``prefill_serial``.  On both paths every other row's
+    state is bit-untouched, and ``moe_counts`` appends the routing counts
+    of the valid tokens (None for stacks without experts).
 
-    Because the non-admitted rows are pure masked filler, a prefill chunk
-    may run while a ``decode_many`` block is still in flight on other
-    slots: the chunk's stale view of those slots' ``slot_pos`` is harmless
-    (filler rows never commit), so chunked prefill composes with the
-    engine's async double-buffered dispatch without a drain.
+    Because the other rows are untouched, a prefill chunk may run while a
+    ``decode_many`` block is still in flight on other slots: the chunk's
+    stale view of those slots' ``slot_pos`` is harmless, so chunked
+    prefill composes with the engine's async double-buffered dispatch
+    without a drain.
     """
+    if not transformer.kv_state_only(cfg):
+        return prefill_serial(p, cfg, tokens, valid, slot, state, slot_pos,
+                              start, reset, moe_counts=moe_counts)
+    state = _reset_row(state, slot, reset)
+    valid = valid.astype(bool)
+    x = embed(cfg, p["embed"], tokens.astype(jnp.int32)[None])
+    with ops.active_rows(valid):
+        state, cnt = transformer.prefill_stack(
+            p["stack"], cfg, x, state, slot, jnp.asarray(start, jnp.int32),
+            valid)
+    if moe_counts:
+        return state, cnt
+    return state
+
+
+def prefill_serial(p: Params, cfg: ArchConfig, tokens: jax.Array,
+                   valid: jax.Array, slot: jax.Array, state: Params,
+                   slot_pos: jax.Array, start: jax.Array = 0,
+                   reset: jax.Array = True, *, moe_counts: bool = False):
+    """``prefill_into_slot`` token by token, for every state family: scans
+    ``masked_decode_step`` over the P positions with per-slot positions,
+    committing state **only at the admitted row on valid steps** (the
+    other rows run as masked filler).  Every per-layer state leaf carries
+    batch at axis 1: (L, B, ...)."""
     b = slot_pos.shape[0]
     onehot = jnp.arange(b) == slot
-    # zero-reset the admitted row: recurrent families (SSM / RG-LRU) carry
-    # state across tokens, and the freed slot's old trajectory must not
-    # bleed into the new request.  KV slots past the position are masked,
-    # but a masked slot still enters the value sum with weight 0, and
-    # 0 * NaN is NaN: a row quarantined by ``nan_guard`` would poison its
-    # slot's next request.
     state = _reset_row(state, slot, reset)
     start = jnp.asarray(start, jnp.int32)
 

@@ -13,14 +13,16 @@ only its own experts' part; the shared experts are held whole.
 Two kinds of path:
 
   * **serving** (``decode_moe``): dropless and per-token, for the decode
-    step and the token-serial prefill.  Every held expert runs on every
-    row and each row keeps the outputs of the held experts among its
-    top-k, weighted by their gates: a row's output depends on its own
-    hidden state alone, never on its batch neighbours or on the filler
-    rows of idle slots.  At serving batch sizes (a few rows) this exact
-    form reads each held expert's weights once a step, which is what a
-    grouped kernel would read too.  Returns on-device routing counts for
-    the rows that commit (``ServeEngine`` sums them into its counters).
+    step and the one-pass prefill of a prompt chunk (one row a token).
+    Every held expert runs on every row and each row keeps the outputs
+    of the held experts among its top-k, weighted by their gates: a
+    row's output depends on its own hidden state alone, never on its
+    batch neighbours or on the filler rows of idle slots.  At serving
+    batch sizes (a few rows, or a 128-token chunk) this exact form reads
+    each held expert's weights once a call, which is what a grouped
+    kernel would read too, and its compute stays under that read.
+    Returns on-device routing counts for the rows that commit
+    (``ServeEngine`` sums them into its counters).
   * **forward / training** (``apply_moe``): capacity-bounded and
     *sort-based* (no (T, E, C) one-hot tensors -- those are O(T*E*C) and
     unlowerable at production shapes).  The router bitmap plays the CSB

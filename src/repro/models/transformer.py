@@ -73,11 +73,13 @@ def apply_dense_layer(p: Params, cfg: ArchConfig, x: jax.Array, *,
     return x + apply_mlp(p["mlp"], cfg, h)
 
 
-def decode_dense_layer(p: Params, cfg: ArchConfig, x, cache, pos, layer, *,
-                       window: int = 0, commit=None):
+def decode_dense_layer(p: Params, cfg: ArchConfig, x, cache, attend):
+    """One dense layer over the stacked KV caches.  ``attend(attn_params,
+    h, cache) -> (out, cache)`` is its attention: ``attention.decode_step``
+    for one token of each row, ``attention.prefill_step`` for a prompt
+    chunk of one row."""
     h = apply_norm(p["ln1"], cfg, x)
-    o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos, layer,
-                                     window=window, commit=commit)
+    o, cache = attend(p["attn"], h, cache)
     x = x + o
     h = apply_norm(p["ln2"], cfg, x)
     return x + apply_mlp(p["mlp"], cfg, h), cache
@@ -104,17 +106,18 @@ def apply_moe_layer(p: Params, cfg: ArchConfig, x: jax.Array, *,
     return x + moe_mod.apply_moe(p["moe"], cfg, h)
 
 
-def decode_moe_layer(p: Params, cfg: ArchConfig, x, cache, pos, layer, *,
-                     commit=None):
-    """One MoE layer's decode step; also returns the routing counts of the
-    committing rows (``moe.decode_moe``)."""
+def decode_moe_layer(p: Params, cfg: ArchConfig, x, cache, attend, rows):
+    """``decode_dense_layer`` with the dropless expert layer in place of
+    the MLP, one token a row; also returns the routing counts of the
+    tokens that ``rows`` (None: all) marks (``moe.decode_moe``)."""
     h = apply_norm(p["ln1"], cfg, x)
-    o, cache = attention.decode_step(p["attn"], cfg, h, cache, pos, layer,
-                                     commit=commit)
+    o, cache = attend(p["attn"], h, cache)
     x = x + o
     h = apply_norm(p["ln2"], cfg, x)
-    y, counts = moe_mod.decode_moe(p["moe"], cfg, h, rows=commit)
-    return x + y, cache, counts
+    b, t, d = h.shape
+    y, counts = moe_mod.decode_moe(p["moe"], cfg, h.reshape(b * t, 1, d),
+                                   rows=rows)
+    return x + y.reshape(x.shape), cache, counts
 
 
 def init_ssm_layer(cfg: ArchConfig, rng, dtype=jnp.bfloat16) -> Params:
@@ -203,8 +206,10 @@ def decode_griffin_group(p: Params, cfg: ArchConfig, x, kv, rec, pos, layer,
             rec[key] = _commit_rows(commit, new, rec[key])
         else:
             x, kv[key] = decode_dense_layer(
-                p[key], cfg, x, kv[key], pos, layer, window=cfg.window,
-                commit=commit)
+                p[key], cfg, x, kv[key],
+                lambda pa, h, c: attention.decode_step(
+                    pa, cfg, h, c, pos, layer, window=cfg.window,
+                    commit=commit))
     return x, kv, rec
 
 
@@ -492,31 +497,67 @@ def decode_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
                 state["trailing"])
         return x_out, new, None
 
-    def kv_step(decode_layer, **kw):
-        def step(lp, h, kv, _, layer):
-            h, kv = decode_layer(lp, cfg, h, kv, pos, layer, commit=commit,
-                                 **kw)
-            return h, kv, None
-        return step
+    def attend(pa, h, kv, layer):
+        return attention.decode_step(pa, cfg, h, kv, pos, layer,
+                                     window=cfg.window, commit=commit)
+    return _kv_stack(p, cfg, x, state, attend, commit)
 
-    if cfg.moe.enabled:
-        new = {}
-        x_out = x
-        if "dense_layers" in p:
-            x_out, new["dense_layers"], _ = _scan_layers(
-                kv_step(decode_dense_layer), x_out, p["dense_layers"],
-                state["dense_layers"])
 
-        def moe_step(lp, h, kv, _, layer):
-            return decode_moe_layer(lp, cfg, h, kv, pos, layer,
-                                    commit=commit)
-        x_out, new["layers"], counts = _scan_layers(
-            moe_step, x_out, p["layers"], state["layers"])
-        return x_out, new, counts.sum(0)
+def _kv_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
+              attend, rows: Optional[jax.Array]
+              ) -> Tuple[jax.Array, Params, Optional[jax.Array]]:
+    """The layer scans of a stack whose state is KV caches alone (dense;
+    MoE after its leading dense layers), shared by the decode step and the
+    one-pass prefill.  ``attend(attn_params, h, kv, layer)`` is each
+    layer's attention over the stacked caches, ``rows`` the tokens that
+    route and count in the expert layers.  Returns the hidden state, the
+    new decode state and, for MoE stacks, the routing counts summed over
+    the MoE layers (None otherwise)."""
+    def dense_step(lp, h, kv, _, layer):
+        h, kv = decode_dense_layer(
+            lp, cfg, h, kv, lambda pa, y, c: attend(pa, y, c, layer))
+        return h, kv, None
 
-    x_out, kv, _ = _scan_layers(kv_step(decode_dense_layer, window=cfg.window),
-                                x, p["layers"], state["layers"])
-    return x_out, {"layers": kv}, None
+    def moe_step(lp, h, kv, _, layer):
+        return decode_moe_layer(
+            lp, cfg, h, kv, lambda pa, y, c: attend(pa, y, c, layer), rows)
+
+    new = {}
+    if "dense_layers" in p:
+        x, new["dense_layers"], _ = _scan_layers(
+            dense_step, x, p["dense_layers"], state["dense_layers"])
+    x, new["layers"], counts = _scan_layers(
+        moe_step if cfg.moe.enabled else dense_step, x, p["layers"],
+        state["layers"])
+    return x, new, None if counts is None else counts.sum(0)
+
+
+def kv_state_only(cfg: ArchConfig) -> bool:
+    """Whether the stack's decode state is only full-length KV caches
+    (dense without ``window``; MoE, with its leading dense layers): no
+    recurrent state, ring cache or encoder memory."""
+    return not (cfg.encoder_decoder or cfg.ssm.enabled or cfg.rglru.enabled
+                or cfg.window)
+
+
+def prefill_stack(p: Params, cfg: ArchConfig, x: jax.Array, state: Params,
+                  row: jax.Array, start: jax.Array, valid: jax.Array
+                  ) -> Tuple[Params, Optional[jax.Array]]:
+    """One forward pass of W prompt tokens of batch row ``row`` through a
+    ``kv_state_only`` stack.  x (1,W,D) holds the tokens at positions
+    ``start …``; ``valid`` (W,) marks the real ones.
+
+    The layers are the decode step's (``_kv_stack``) with
+    ``attention.prefill_step`` for attention: the stacked KV caches ride
+    whole in the layer scan's carry, and each layer writes the valid
+    tokens' K/V into its row in place.  Returns the new decode state and,
+    for MoE stacks, the routing counts of the valid tokens (None
+    otherwise).  No final norm or head: the caller keeps no logits."""
+    def attend(pa, h, kv, layer):
+        return attention.prefill_step(pa, cfg, h, kv, layer, row, start,
+                                      valid)
+    _, new, counts = _kv_stack(p, cfg, x, state, attend, valid)
+    return new, counts
 
 
 def decode_stack_window(p: Params, cfg: ArchConfig, x: jax.Array,

@@ -120,7 +120,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.kernels import ops
-from repro.models import model as model_lib
+from repro.models import model as model_lib, transformer
 
 
 def decode_exec_config(cfg: ArchConfig, n_slots: int, *,
@@ -559,12 +559,17 @@ class ServeEngine:
         self.demote_margin = float(demote_margin)
         # lifetime counters: one per terminal state, demotions, and the
         # work dispatched (admissions, prefill segments with their valid
-        # tokens and padded device steps, fused decode / verify blocks
-        # with their scanned steps, token-block syncs)
+        # tokens and padded device steps and how many ran as one forward
+        # pass, fused decode / verify blocks with their scanned steps,
+        # token-block syncs)
         self.counters = {s: 0 for s in TERMINAL_STATES}
         self.counters.update(demotions=0, admitted=0, prefill_segments=0,
                              prefill_tokens=0, prefill_steps=0,
-                             decode_blocks=0, decode_steps=0, syncs=0)
+                             prefill_forwards=0, decode_blocks=0,
+                             decode_steps=0, syncs=0)
+        # KV-only stacks prefill a segment in one forward pass, the rest
+        # token by token (``model_lib.prefill_into_slot``)
+        self._prefill_forward = transformer.kv_state_only(cfg)
         # MoE stacks: routing picks of the rows that commit, counted on the
         # device by every decode block and prefill segment
         # (``moe.decode_moe``): over all routed experts, and per held
@@ -1219,17 +1224,18 @@ class ServeEngine:
 
     def _feed_prefill(self, i: int, start: int, count: int):
         """Feed ``count`` prompt-feed tokens from ``start`` into slot ``i``
-        — one fused jitted call (``models.model.prefill_into_slot``): the
-        segment scans on-device with slot masking, so host dispatch is O(1)
-        per segment instead of O(segment_len).
+        — one fused jitted call (``models.model.prefill_into_slot``): one
+        forward pass of the segment for KV-only stacks, an on-device
+        token-serial scan for the rest, so host dispatch is O(1) per
+        segment instead of O(segment_len).
 
-        Slot masking merges state **only at the fed row on valid steps** —
-        live slots keep their rows bit-untouched (every per-layer state
-        leaf carries batch at axis 1: (L, B, ...)), and on the first
-        segment (``start == 0``) the row is zero-reset so recurrent
-        families never inherit the previous occupant's state.  Segments are
-        padded to power-of-two lengths; padding steps are fully masked,
-        bounding traces at O(log max_seq)."""
+        State is written **only at the fed row for valid tokens** — live
+        slots keep their rows bit-untouched (every per-layer state leaf
+        carries batch at axis 1: (L, B, ...)), and on the first segment
+        (``start == 0``) the row is zero-reset so recurrent families never
+        inherit the previous occupant's state.  Segments are padded to
+        power-of-two lengths; padded tokens write nothing, bounding traces
+        at O(log max_seq)."""
         s = self.slots[i]
         feed = np.asarray(s.req.prompt[:-1], np.int32)
         seg = feed[start:start + count]
@@ -1240,6 +1246,7 @@ class ServeEngine:
         self.counters["prefill_segments"] += 1
         self.counters["prefill_tokens"] += len(seg)
         self.counters["prefill_steps"] += padded
+        self.counters["prefill_forwards"] += self._prefill_forward
         with TraceAnnotation("serve.prefill.dispatch", uid=s.req.uid,
                              tokens=len(seg), steps=padded):
             self.state, moe = self._prefill(self._exec_params, self.state,
@@ -1734,13 +1741,17 @@ class ServeEngine:
         latency; ``flush()`` collects the tail).  Two exceptions keep the
         deferral off the latency paths: a block carrying some live
         request's *first* token is synced in this call (first-token
-        urgency — TTFT never pays the deferral), and no block is
-        speculated while a request could join the live set this tick
-        (``_joinable``).  If block k-1's accounting reveals an occupancy
-        change, the speculative block k is drained in the same call — its
-        tokens are still exact — and the next tick relaunches from host
-        state.  ``async_dispatch=False`` syncs the block it dispatched
-        (classic one-block-per-call behaviour).
+        urgency — TTFT never pays the deferral) unless the call already
+        credited tokens, and no block is speculated while a request could
+        join the live set this tick (``_joinable``).  A call never holds
+        tokens it has credited while it waits on a further block: if block
+        k-1's accounting reveals an occupancy change, the call returns and
+        the next one accounts the speculative block k — its tokens are
+        still exact — and relaunches from host state; a first-token block
+        launched after tokens were credited is synced by the next call,
+        after it launches that block's successor.  ``async_dispatch=False``
+        syncs the block it dispatched (classic one-block-per-call
+        behaviour).
         """
         with TraceAnnotation("serve.tick"):
             return self._tick(n_steps)
@@ -1765,11 +1776,12 @@ class ServeEngine:
                     self._launch(live, t_spec)
                     launched = True
             if self._account_one(out) and launched:
-                # occupancy changed under the speculative block: drain it
-                # cleanly (finished rows emitted sentinels, its tokens are
-                # exact) and relaunch from host state below
-                self._account_one(out)
-                launched = False
+                # occupancy changed under the speculative block: return the
+                # credited tokens now, a finishing request's last ones among
+                # them, rather than hold them while it runs; the next tick
+                # accounts it (finished rows emitted sentinels, its tokens
+                # are exact) and relaunches from host state
+                return out
         elif self._inflight:
             out = self.flush()
         self._admit()
@@ -1784,9 +1796,12 @@ class ServeEngine:
         # that latency straight into its TTFT.  Sync such blocks on the
         # spot; defer only in the steady state where every live request is
         # already streaming (the carry is still set, so the next tick
-        # speculates from device state either way).
+        # speculates from device state either way).  A tick that already
+        # credited tokens returns them instead: waiting here would hold
+        # them, a finishing request's last ones among them, for the whole
+        # new block; the next tick syncs it after launching its successor.
         if not self.async_dispatch \
-                or any(not self.slots[i].req.out for i in live):
+                or (not out and any(not self.slots[i].req.out for i in live)):
             self._account_one(out)
         return out
 

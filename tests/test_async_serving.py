@@ -5,7 +5,7 @@ syncing block k's token array (deferring host accounting by one block),
 and admission is a pluggable policy.  These tests pin the contract:
 async ≡ sync ≡ per-token oracle token-for-token — across state families,
 greedy and sampled, under randomized staggered arrivals — plus the
-occupancy-change drain rule, EOS inside a deferred block, ``flush()``
+occupancy-change rule, EOS inside a deferred block, ``flush()``
 semantics, the device-carry launch fast path, and the
 ``AdaptiveAdmission`` policy surface.
 """
@@ -184,6 +184,66 @@ def test_occupancy_change_mid_speculation():
     ores = oracle.run_until_drained()
     for uid, ouid in zip(uids, ouids):
         assert req_by_uid[uid].out == ores[ouid]
+
+
+def _oracle_streams(reqs):
+    cfg, params = _tiny()
+    oracle = ServeEngine(cfg, params, n_slots=2, max_seq=48, fused=False)
+    uids = [oracle.submit(p, max_new=mn) for p, mn in reqs]
+    out = oracle.run_until_drained()
+    return [out[u] for u in uids]
+
+
+def _collect(streams, out):
+    for uid, toks in out.items():
+        streams.setdefault(uid, []).extend(toks)
+    return out
+
+
+def test_tick_returns_credited_tokens_before_a_first_token_block():
+    """A tick that has credited tokens returns them at once: the last
+    token of a finishing request is not held while a newly admitted
+    request's first-token block runs.  The next tick launches that
+    block's successor, then syncs it."""
+    cfg, params = _tiny()
+    eng = ServeEngine(cfg, params, n_slots=2, max_seq=48, decode_block=4)
+    reqs = [(np.array([3, 5, 7], np.int32), 5),
+            (np.array([2, 4], np.int32), 6)]
+    got = {}
+    a = eng.submit(reqs[0][0], max_new=reqs[0][1])
+    assert len(_collect(got, eng.decode_block_step())[a]) == 4  # urgency
+    assert _collect(got, eng.decode_block_step()) == {}  # a's last step
+    b = eng.submit(reqs[1][0], max_new=reqs[1][1])
+    out = _collect(got, eng.decode_block_step())  # a accounted, b admitted
+    assert len(out[a]) == 1 and b not in out
+    assert len(eng._inflight) == 1        # b's first block, not synced
+    out = _collect(got, eng.decode_block_step())
+    assert len(out[b]) == 4 and len(eng._inflight) == 1  # successor out
+    _collect(got, eng.flush())
+    assert [got[a], got[b]] == _oracle_streams(reqs)
+
+
+def test_occupancy_change_returns_without_draining():
+    """A request finishing inside block k while block k+1 was launched
+    speculatively: the tick returns block k's tokens at once, the finished
+    request's last ones among them, and leaves block k+1 in flight; the
+    next tick accounts it.  Streams stay oracle-exact."""
+    cfg, params = _tiny()
+    eng = ServeEngine(cfg, params, n_slots=2, max_seq=48, decode_block=4)
+    reqs = [(np.array([3, 5, 7], np.int32), 6),
+            (np.array([2, 4], np.int32), 14)]
+    a, b = [eng.submit(p, max_new=mn) for p, mn in reqs]
+    got = {}
+    out = _collect(got, eng.decode_block_step())     # first block, synced
+    assert len(out[a]) == len(out[b]) == 4
+    assert _collect(got, eng.decode_block_step()) == {}   # deferred
+    out = _collect(got, eng.decode_block_step())  # speculate, account: a done
+    assert len(out[a]) == 2 and len(got[a]) == 6
+    assert len(eng._inflight) == 1        # the speculative block, undrained
+    out = _collect(got, eng.decode_block_step())
+    assert len(out[b]) == 4 and a not in out
+    _collect(got, eng.flush())
+    assert [got[a], got[b]] == _oracle_streams(reqs)
 
 
 def test_eos_in_deferred_block():

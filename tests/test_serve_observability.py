@@ -16,8 +16,8 @@ from repro.serve import ServeEngine, VirtualClock
 from repro.serve import engine as engine_mod
 
 DISPATCH_COUNTERS = ("admitted", "prefill_segments", "prefill_tokens",
-                     "prefill_steps", "decode_blocks", "decode_steps",
-                     "syncs")
+                     "prefill_steps", "prefill_forwards", "decode_blocks",
+                     "decode_steps", "syncs")
 
 
 @functools.lru_cache(maxsize=None)
@@ -135,10 +135,33 @@ def test_counters_equal_the_dispatched_work(async_dispatch, chunk):
     assert delta == {"admitted": 3, "prefill_segments": seen["segments"],
                      "prefill_tokens": seen["valid"],
                      "prefill_steps": seen["padded"],
+                     "prefill_forwards": seen["segments"],
                      "decode_blocks": seen["blocks"],
                      "decode_steps": seen["steps"],
                      "syncs": seen["blocks"]}
     assert delta["prefill_tokens"] == 10 + 1 + 5
+
+
+@pytest.mark.parametrize("arch,one_pass", [
+    ("stablelm-1.6b", True), ("deepseek-moe-16b", True),
+    ("mamba2-1.3b", False), ("recurrentgemma-9b", False)])
+def test_prefill_forwards_count_one_pass_segments(arch, one_pass):
+    """``prefill_forwards`` counts the segments fed in one forward pass:
+    every segment of a KV-only stack (dense, MoE), none of an SSM or
+    RG-LRU stack, which keep the token-serial scan."""
+    from repro.configs.base import get_smoke_config
+    cfg = get_smoke_config(arch)
+    eng = ServeEngine(cfg, model_lib.init_params(cfg, jax.random.PRNGKey(0),
+                                                 dtype=jnp.float32),
+                      n_slots=2, max_seq=32, decode_block=4,
+                      dtype=jnp.float32, prefill_chunk=4)
+    for n in (11, 3):
+        eng.submit(np.arange(1, n + 1), max_new=2)
+    _drive(eng)
+    c = eng.health()["counters"]
+    assert c["prefill_segments"] == 3 + 1
+    assert c["prefill_forwards"] == (c["prefill_segments"] if one_pass
+                                     else 0)
 
 
 def test_verify_blocks_count_their_window():

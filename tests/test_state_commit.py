@@ -7,7 +7,12 @@ come back bit-identical, and an active row's state (and every row's
 logits) must equal what the select-based commit gives: run the step for
 every row, then ``jnp.where(active, new, old)`` over each whole state
 leaf.  That reference lives here, in a few lines, and runs through the
-same ``decode_many`` / ``prefill_into_slot`` loops.
+same ``decode_many`` loop and the token-serial prefill scan,
+``prefill_serial``.  Stacks of KV caches alone (dense, MoE) prefill in
+one forward pass instead, which rounds in another order: there the
+admitted row is compared to the reference scan within a tolerance and
+every other row bit for bit (``tests/test_prefill_forward.py`` covers
+that path's cases in full).
 
 The structural test guards the mechanism: lowered on the smoke StableLM
 config, neither fused entry point holds a ``select`` over the whole
@@ -23,7 +28,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import get_smoke_config
-from repro.models import model as M
+from repro.models import model as M, transformer
 
 B, MAX_SEQ = 4, 16
 ACTIVE = np.array([True, False, True, False])
@@ -157,8 +162,24 @@ def test_prefill_into_slot_commits_admitted_row_only(family, reset,
                             reset=jnp.asarray(reset))
     got = jax.jit(run)(params, state=state)
     reference()
-    want = run(params, state=state)
-    _assert_rows(got, want, state, np.arange(B) != 2)
+    if not transformer.kv_state_only(cfg):
+        _assert_rows(got, run(params, state=state), state, np.arange(B) != 2)
+        return
+    # the one-pass prefill against the select-based token-serial scan
+    want = functools.partial(M.prefill_serial, cfg=cfg, tokens=toks,
+                             valid=valid, slot=jnp.int32(2),
+                             slot_pos=slot_pos, start=jnp.int32(3),
+                             reset=jnp.asarray(reset))(params, state=state)
+    kept = np.arange(B) != 2
+    for (path, g), w, b in zip(jax.tree_util.tree_leaves_with_path(got),
+                               jax.tree.leaves(want), jax.tree.leaves(state)):
+        g, w, b = np.asarray(g), np.asarray(w), np.asarray(b)
+        where = jax.tree_util.keystr(path)
+        np.testing.assert_allclose(g[:, 2], w[:, 2], rtol=1e-5, atol=1e-5,
+                                   err_msg=where)
+        np.testing.assert_array_equal(g[:, kept].view(np.uint8),
+                                      b[:, kept].view(np.uint8),
+                                      err_msg=where)
 
 
 # --- the flat cache read -----------------------------------------------------

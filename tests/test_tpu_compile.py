@@ -217,8 +217,9 @@ def test_decode_state_commits_in_place(one_chip):
 
 
 def _top_level_labels(hlo: str):
-    """``trace.op_label`` of every instruction outside fused computations:
-    the operations a device trace shows as events."""
+    """``trace.op_label`` of every instruction outside fused computations
+    (those a ``fusion`` instruction calls, nested ones included): the
+    operations a device trace shows as events."""
     import importlib.util
     import sys
     from pathlib import Path
@@ -226,10 +227,14 @@ def _top_level_labels(hlo: str):
     spec = importlib.util.spec_from_file_location("bench_trace", path)
     trace = sys.modules[spec.name] = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(trace)
+    called = {m.group(1) for line in hlo.splitlines() if " fusion(" in line
+              for m in [re.search(r"calls=%([\w.\-]+)", line)] if m}
     labels, fused = set(), False
     for line in hlo.splitlines():
         if line and not line[0].isspace():
-            fused = "fused" in line.split("(")[0]
+            name = line.split("(")[0].split()[-1].lstrip("%") \
+                if "(" in line else ""
+            fused = "fused" in line.split("(")[0] or name in called
         elif not fused and " = " in line:
             label = trace.op_label(line.strip().removeprefix("ROOT "))
             if label is not None:
@@ -241,10 +246,13 @@ def test_moe_share_decode_block_fits_one_chip(one_chip):
     """The DeepSeek-MoE-16B chip share of the benchmark (28 layers, 8 of
     64 routed experts held, 8 slots of 1024 positions, bf16) compiles as a
     16-step decode block and a 128-token prefill segment for one v5e; the
-    arguments and temporaries of each fit its 16 GiB, and the expert
-    operations the ``expert_ms_per_step`` reader picks by shape are there:
-    the up/gate projection and the down projection fused with the gated
-    sum, one each."""
+    arguments and temporaries of each fit its 16 GiB, and each holds the
+    expert operations the ``expert_ms_per_step`` rule picks by shape: the
+    up/gate projection and the down projection fused with the gated sum,
+    one each.  The decode block's rows are the engine's 8 slots; the
+    prefill segment runs as one forward pass, so its rows are its 128
+    tokens.  The ``expert_ms_per_pass`` rule, which takes every row count
+    the engine runs the experts at, picks the same two in each."""
     import importlib.util
     import json
     from pathlib import Path
@@ -261,6 +269,10 @@ def test_moe_share_decode_block_fits_one_chip(one_chip):
         "bench_expert_ms", bench / "metrics" / "expert_ms_per_step.py")
     reader = importlib.util.module_from_spec(rspec)
     rspec.loader.exec_module(reader)
+    pspec = importlib.util.spec_from_file_location(
+        "bench_expert_pass", bench / "metrics" / "expert_ms_per_pass.py")
+    per_pass = importlib.util.module_from_spec(pspec)
+    pspec.loader.exec_module(per_pass)
 
     def on_chip(tree):
         return jax.tree.map(
@@ -284,13 +296,19 @@ def test_moe_share_decode_block_fits_one_chip(one_chip):
             params, state, _shape((128,), jnp.int32, one_chip),
             _shape((128,), jnp.bool_, one_chip), scalar, rows, scalar,
             _shape((), jnp.bool_, one_chip))
-    for name, lowered in (("decode_many", decode), ("prefill", prefill)):
+    for name, lowered, rows_in in (("decode_many", decode, n_slots),
+                                   ("prefill", prefill, 128)):
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
         assert total < 16 * 2 ** 30, (name, total)
         labels = _top_level_labels(compiled.as_text())
         picked = reader.expert_labels(dict.fromkeys(labels, 0.0),
-                                      spec["model"], spec["engine"])
-        assert sorted(picked) == ["fusion bf16[8,8,1408]",
-                                  "fusion f32[8,2048]"], (name, picked)
+                                      spec["model"],
+                                      {**spec["engine"], "n_slots": rows_in})
+        assert sorted(picked) == [f"fusion bf16[8,{rows_in},1408]",
+                                  f"fusion f32[{rows_in},2048]"], \
+            (name, picked)
+        assert sorted(per_pass.expert_labels(
+            dict.fromkeys(labels, 0.0), spec["model"], spec["engine"])) == \
+            sorted(picked), name
