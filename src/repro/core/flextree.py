@@ -4,8 +4,7 @@ Two levels, per DESIGN.md §2:
 
 1. **Cycle model** of the hardware adder tree: flexible output tap points at
    every level (`IC_P ∈ {1..16}`, non-powers-of-2 zero-padded) vs (a) a
-   neighbor-to-neighbor psum chain and (b) a fixed root-only tree.  Feeds
-   ``benchmarks/bench_flextree.py``.
+   neighbor-to-neighbor psum chain and (b) a fixed root-only tree.
 
 2. **Mesh-level reduction strategies** for the JAX framework: the K/expert
    contraction partitioned ``ic_p`` ways across a mesh axis, combined by a

@@ -19,7 +19,7 @@ in stem/1x1-reduce layers and high in wide mid/late convs.
 from __future__ import annotations
 
 import math
-from typing import List, Sequence, Tuple
+from typing import List, Sequence
 
 import numpy as np
 
@@ -71,13 +71,3 @@ def profiles_for(network: str, layers: Sequence[ConvLayer]
     act[0] = min(act[0], 0.05)
     return [SparsityStats(act_density=1.0 - float(a), wt_density=1.0 - float(w))
             for a, w in zip(act, wt)]
-
-
-def network_sparsity(stats: Sequence[SparsityStats],
-                     layers: Sequence[ConvLayer]) -> Tuple[float, float]:
-    """MAC-weighted (weight_sp, act_sp) at network level."""
-    macs = np.asarray([l.macs for l in layers], dtype=np.float64)
-    macs /= macs.sum()
-    wt = sum((1.0 - s.wt_density) * m for s, m in zip(stats, macs))
-    act = sum((1.0 - s.act_density) * m for s, m in zip(stats, macs))
-    return float(wt), float(act)

@@ -40,33 +40,30 @@ executables whose host cost is O(1) per *batch of tokens*:
     are inputs to every subsequent call, never outputs, so donating them
     would consume live buffers for zero aliasing benefit.
 
-**Async double-buffered dispatch** (the tentpole of ISSUE 7): even with the
-fused block, the host still sat on the critical path — each (T, n_slots)
-token block was synced (and its EOS/truncation accounting run) before the
-next block was dispatched, so the device idled for the whole host-side
-bookkeeping window (``host_frac ≈ 0.5`` on the edge profile).  With
-``async_dispatch`` (the default), block k+1 is dispatched from the
-device-resident (token, pos, rem) carries *before* block k's token array is
-synced: host accounting for block k then overlaps device compute for block
-k+1.  Host-side truncation/EOS accounting and occupancy updates are
-deferred by exactly one block.  The drain rule keeps this exact: a block is
-only speculated while the live set is unchanged (keyed by (slot, uid)
-pairs, so a recycled slot can never inherit a stale carry), and when block
-k's accounting reveals an occupancy change — a request finished, a prefill
-completed — the speculative block is drained cleanly: its tokens are still
-oracle-exact (rows that stopped emit the ``-1`` sentinel and never touch
-state), it just ran without the admission the host would now like to make.
-Two gates keep the deferral off the latency paths of the serving tick
-(``decode_block_step``): a block carrying some request's *first* token is
-synced in its own tick (first-token urgency — TTFT never pays the
-one-block deferral), and speculation is skipped while a request could
-join the live set this tick (``_joinable``: a slot mid-prefill, or a
-queued request with a free slot), so late joiners board the very next
-launch.  ``run_until_drained`` — a batch drain with no TTFT to protect —
-speculates whenever the carries are valid.  ``flush()`` syncs any
-in-flight block on demand;
-the per-token ``step()``, ``warmup()`` and ``maybe_recalibrate()`` flush
-implicitly.
+**Double-buffered dispatch**: the host would sit on the critical path if
+each (T, n_slots) token block were synced (and its EOS/truncation
+accounting run) before the next block was dispatched — the device would
+idle for the whole host-side bookkeeping window.  Instead block k+1 is
+dispatched from the device-resident (token, pos, rem) carries *before*
+block k's token array is synced: host accounting for block k then
+overlaps device compute for block k+1.  Host-side truncation/EOS
+accounting and occupancy updates are deferred by exactly one block.  A
+block is only speculated while the live set is unchanged (keyed by (slot,
+uid) pairs, so a recycled slot can never inherit a stale carry); when
+block k's accounting reveals an occupancy change — a request finished, a
+prefill completed — the tick returns block k's tokens and the next tick
+accounts the speculative block (its tokens are still oracle-exact: rows
+that stopped emit the ``-1`` sentinel and never touch state) and
+relaunches from host state.  Two gates keep the deferral off the latency
+paths: a block carrying some request's *first* token is synced in its own
+tick (first-token urgency — TTFT never pays the one-block deferral), and
+speculation is skipped while a request could join the live set this tick
+(``_joinable``: a slot mid-prefill, or a queued request with a free
+slot), so late joiners board the very next launch.  The serving tick
+(``decode_block_step``) is the one loop: ``run_until_drained`` is ticks
+until the engine is drained.  ``flush()`` syncs any in-flight block on
+demand; the per-token ``step()``, ``warmup()`` and
+``maybe_recalibrate()`` flush implicitly.
 
 **Admission policy** (``AdmissionPolicy``): which queued request a freed
 slot takes, and how large a prefill chunk each tick feeds, are policy — not
@@ -83,11 +80,10 @@ The per-token ``step()`` API is kept as the reference oracle: it runs the
 same per-slot-position ``decode_step`` one token at a time, and the fused
 block is computation-identical to T oracle steps (test-enforced
 token-for-token across dense, planned-sparse MoE and tied-head families).
-``run_until_drained`` drives the fused loop (``fused=False`` falls back to
-the oracle loop — the per-token baseline the throughput bench measures
-against), picking each block length as the max live-slot remaining budget
-clamped to ``decode_block``; per-slot device budgets stop each row at its
-own limit so no slot overshoots its request.
+``run_until_drained`` drives the fused loop (``fused=False`` runs the
+oracle loop instead), picking each block length as the max live-slot
+remaining budget clamped to ``decode_block``; per-slot device budgets stop
+each row at its own limit so no slot overshoots its request.
 
 Sparsity/dataflow wiring: an optional ``ExecConfig`` (see ``kernels.ops``)
 is installed around every decode trace, so the engine's matmul sites consult
@@ -467,22 +463,17 @@ class PriorityAdmission(AdmissionPolicy):
 class ServeEngine:
     """Continuous-batching engine over the fused on-device executables.
 
-    ``fused`` selects the production block-decode loop in
-    ``run_until_drained`` (False = the per-token oracle loop, the baseline
-    the throughput bench measures against); ``decode_block`` caps the fused
-    block length T (host work is O(1) per block); ``donate_state`` lets the
-    fused executables alias the decode state in place (False keeps the
-    state buffers alive across calls — used by timing harnesses that replay
-    one call repeatedly).
+    ``fused`` selects the block-decode loop in ``run_until_drained``
+    (False = the per-token oracle loop the tests compare against);
+    ``decode_block`` caps the fused block length T (host work is O(1) per
+    block).
 
-    ``async_dispatch`` (default True) double-buffers the fused loop: block
-    k+1 is dispatched from the device-resident (token, pos, rem) carries
-    *before* block k's token array is synced, so block k's host accounting
-    overlaps block k+1's device compute (``async_dispatch=False`` is the
-    sync baseline the async/sync host-overhead series measures against).
-    Token streams are unchanged either way — only dispatch order moves.
-    A block may be left in flight between ``decode_block_step`` calls; its
-    tokens are credited on the next call (or by ``flush()``).
+    The fused loop is double-buffered: block k+1 is dispatched from the
+    device-resident (token, pos, rem) carries *before* block k's token
+    array is synced, so block k's host accounting overlaps block k+1's
+    device compute.  A block may be left in flight between
+    ``decode_block_step`` calls; its tokens are credited on the next call
+    (or by ``flush()``).
 
     ``admission`` plugs the admission policy (queue ordering + prefill
     chunk sizing); the default ``FIFOAdmission`` reproduces the classic
@@ -496,10 +487,9 @@ class ServeEngine:
                  max_seq: int = 256, dtype=jnp.float32,
                  exec_cfg: Optional[ops.ExecConfig] = None,
                  verify_plan: bool = True, fused: bool = True,
-                 decode_block: int = 16, donate_state: bool = True,
+                 decode_block: int = 16,
                  eos_id: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
-                 async_dispatch: bool = True,
                  admission: Optional[AdmissionPolicy] = None,
                  quantize: bool = False,
                  plan_tiers: Optional[Sequence[float]] = None,
@@ -514,7 +504,6 @@ class ServeEngine:
         self.exec_cfg = exec_cfg
         self.fused = fused
         self.decode_block = decode_block
-        self.donate_state = donate_state
         # on-device stop token: a slot emitting eos_id goes inactive inside
         # the scanned block (None disables — budgets alone size requests)
         self.eos_id = eos_id
@@ -526,7 +515,6 @@ class ServeEngine:
                              f"got {prefill_chunk}")
         self.prefill_chunk = prefill_chunk
         self._prefill_rr = 0          # round-robin over mid-prefill slots
-        self.async_dispatch = async_dispatch
         if admission is not None and not isinstance(admission,
                                                     AdmissionPolicy):
             raise TypeError(f"admission must be an AdmissionPolicy, got "
@@ -726,14 +714,13 @@ class ServeEngine:
         and after ``maybe_recalibrate`` swaps the exec config — the new
         jits re-trace under the new descriptor table on their next call.
 
-        The fused executables donate the decode-state argument (argnum 1):
-        the KV / recurrent caches alias in place instead of being copied
-        every block.  The per-token oracle stays undonated — it is the
-        reference path, and keeping its inputs alive makes it safe to
-        replay against held state copies in tests and benches.
+        The fused executables donate the decode-state argument (argnum 1,
+        argnum 2 of the verify block): the KV / recurrent caches alias in
+        place instead of being copied every block.  The per-token oracle
+        stays undonated — it is the reference path, and keeping its inputs
+        alive makes it safe to replay against held state copies in tests.
         """
         cfg = self.cfg
-        donate = (1,) if self.donate_state else ()
         eos_id = self.eos_id
         nan_guard = self.nan_guard
 
@@ -777,13 +764,11 @@ class ServeEngine:
         self._decode = jax.jit(self._scoped(serve_decode))
         self._decode_many = jax.jit(self._scoped(serve_decode_many),
                                     static_argnums=(9,),
-                                    donate_argnums=donate)
+                                    donate_argnums=(1,))
         self._prefill = jax.jit(self._scoped(serve_prefill),
-                                donate_argnums=donate)
+                                donate_argnums=(1,))
         self._verify = jax.jit(self._scoped(serve_verify),
-                               static_argnums=(10, 11),
-                               donate_argnums=((2,) if self.donate_state
-                                               else ()))
+                               static_argnums=(10, 11), donate_argnums=(2,))
         # stale-trace hygiene: the mask cache holds device arrays handed to
         # the retired executables — clear every per-engine cache alongside
         # the rebuild so nothing compiled against the old table survives
@@ -1549,11 +1534,11 @@ class ServeEngine:
         (token, pos, rem) carries are retained for the next launch.
         ``_account_one`` later pays the deferred host cost.
 
-        Tier routing and the speculate-or-decode choice live here so every
-        launch path (sync, async carry fast path, drain loop) gets them
-        uniformly: a speculative launch dispatches ONE fused verify block
-        (draft tier proposes ``speculate_k``, the block's tier scores all
-        k+1 positions) whose row length is spec_k + 1; a plain launch
+        Tier routing and the speculate-or-decode choice live here so both
+        launch paths (host inputs, device carries) get them uniformly: a
+        speculative launch dispatches ONE fused verify block (draft tier
+        proposes ``speculate_k``, the block's tier scores all k+1
+        positions) whose row length is spec_k + 1; a plain launch
         dispatches ``decode_many`` under the block's tier.  Both return
         carries with identical semantics, so verify and decode blocks
         interleave freely in the double-buffer.  Returns the dispatched
@@ -1700,11 +1685,9 @@ class ServeEngine:
         is mid-prefill, or the queue is non-empty with a free slot.
         Speculating past such a tick would pin the in-flight occupancy for
         one more block and make the joiner wait it out; skipping the
-        speculation makes the tick behave like sync dispatch, so late
-        joiners board the very next launch and async p99 TTFT tracks
-        sync's.  At full occupancy with no pending prefill (the
-        steady-state decode regime) this is False and double-buffering
-        runs uninhibited."""
+        speculation lets late joiners board the very next launch.  At full
+        occupancy with no pending prefill (the steady-state decode regime)
+        this is False and double-buffering runs uninhibited."""
         return bool(self._prefilling()
                     or (self.queue and self._free_slots()))
 
@@ -1715,8 +1698,7 @@ class ServeEngine:
         block, so subtract them before sizing.  Returns 0 when every live
         row will have exhausted its budget inside the pending block —
         speculating would dispatch a pure-sentinel block (EOS can still
-        stop rows earlier; that waste is bounded by one block and drained
-        on the occupancy change)."""
+        stop rows earlier; that waste is bounded by one block)."""
         rem = max(
             min(s.req.max_new - len(s.req.out),
                 (self.max_seq - 1) - s.pos) - inflight_t
@@ -1734,11 +1716,11 @@ class ServeEngine:
         device budgets stop each row at its own limit, so no request
         overshoots.
 
-        With ``async_dispatch`` the tick is double-buffered across calls:
-        block k launches from the device carries *before* block k-1's
-        token sync, so the device never idles over the tick boundary and
-        the returned tokens are the *previous* call's block (one block of
-        latency; ``flush()`` collects the tail).  Two exceptions keep the
+        The tick is double-buffered across calls: block k launches from
+        the device carries *before* block k-1's token sync, so the device
+        never idles over the tick boundary and the returned tokens are the
+        *previous* call's block (one block of latency; ``flush()`` collects
+        the tail).  Two exceptions keep the
         deferral off the latency paths: a block carrying some live
         request's *first* token is synced in this call (first-token
         urgency — TTFT never pays the deferral) unless the call already
@@ -1749,9 +1731,7 @@ class ServeEngine:
         the next one accounts the speculative block k — its tokens are
         still exact — and relaunches from host state; a first-token block
         launched after tokens were credited is synced by the next call,
-        after it launches that block's successor.  ``async_dispatch=False``
-        syncs the block it dispatched (classic one-block-per-call
-        behaviour).
+        after it launches that block's successor.
         """
         with TraceAnnotation("serve.tick"):
             return self._tick(n_steps)
@@ -1766,7 +1746,7 @@ class ServeEngine:
         self._expire_deadlines()
         self._maybe_demote()
         launched = False
-        if self.async_dispatch and self._inflight:
+        if self._inflight:
             live = self._live()
             if live and not self._joinable() and self._carry is not None \
                     and self._carry[0] == self._live_key(live):
@@ -1782,8 +1762,6 @@ class ServeEngine:
                 # accounts it (finished rows emitted sentinels, its tokens
                 # are exact) and relaunches from host state
                 return out
-        elif self._inflight:
-            out = self.flush()
         self._admit()
         self._advance_prefill()
         live = self._live()
@@ -1800,123 +1778,48 @@ class ServeEngine:
         # credited tokens returns them instead: waiting here would hold
         # them, a finishing request's last ones among them, for the whole
         # new block; the next tick syncs it after launching its successor.
-        if not self.async_dispatch \
-                or (not out and any(not self.slots[i].req.out for i in live)):
+        if not out and any(not self.slots[i].req.out for i in live):
             self._account_one(out)
         return out
-
-    def _collect(self, results: Dict[int, List[int]]):
-        for s in self.slots:
-            if s.req is not None and s.req.done:
-                results[s.req.uid] = s.req.out
 
     def _drained(self) -> bool:
         return (not self.queue and not self._prefilling()
                 and all(s.req is None or s.req.done for s in self.slots))
 
     def run_until_drained(self, max_steps: int = 1024) -> Dict[int, List[int]]:
-        """Serve until queue and slots drain (or ``max_steps`` decode
-        steps).  ``fused=True`` drives ``decode_many`` blocks — host work
-        per block is one dispatch and one token-block sync; each iteration
-        also feeds one pending prefill chunk, so long prompts admit across
-        several blocks instead of stalling live decodes.  ``fused=False``
-        is the per-token oracle loop.
+        """Serve until queue, slots and in-flight blocks drain (or
+        ``max_steps`` decode steps), then flush.  ``fused=True`` runs
+        serving ticks (``decode_block_step``), so a drain dispatches exactly
+        what the served loop would; an iteration that dispatched no decode
+        step and synced no block (prefill only) counts one step, so a stuck
+        prefill cannot loop forever.  ``fused=False`` is the per-token
+        oracle loop.
 
-        With ``async_dispatch`` the loop pipelines: while block k is in
-        flight, block k+1 is dispatched from the device-resident (token,
-        pos, rem) carries, *then* block k's token array is synced — block
-        k's host accounting (truncation, EOS, occupancy updates) runs
-        entirely under block k+1's device compute.  Speculation is sized by
-        ``_block_len_ahead`` and gated on the (slot, uid) live-set key —
-        but *not* on ``_joinable``: a batch drain has no TTFT to protect,
-        so it speculates whenever the carries are valid (the serving tick
-        ``decode_block_step`` is the latency-aware path);
-        when block k's accounting changes the occupancy (a request
-        finished, a prefill chunk completed a feed), the in-flight
-        speculative block is drained cleanly and the next block launches
-        from host state — the "clean drain on occupancy change" rule.
-        Self-speculative *verify* blocks ride the same ``_inflight`` queue
-        as decode blocks, so the rule drains them identically (their
-        tokens are verify-tier-exact regardless of when they are synced —
-        regression-tested)."""
+        Returns {uid: tokens} for every request that reached a terminal
+        state during the drain, and for those finished before the call
+        that still hold their slots (read from the record ``results()``
+        reads, so bounded like it to the most recent 4096 terminals)."""
+        c = self.counters
+        held = {s.req.uid for s in self.slots
+                if s.req is not None and s.req.done}
+        before = set(self._retired) - held
         if not self.fused:
-            return self._run_per_token(max_steps)
-        results: Dict[int, List[int]] = {}
-        steps = 0
-        while True:
-            self._expire_deadlines()
-            self._maybe_demote()
-            if not self._inflight:
-                # capture already-finished slots before admission
-                # overwrites them (requests can finish in
-                # decode_block_step/step calls made outside this drain)
-                self._collect(results)
-                self._admit()
-                fed = self._advance_prefill()
-                live = self._live()
-                if not live:
-                    if (fed or self._prefilling()) and steps < max_steps:
-                        # prefill-only iteration: chunks are still landing
-                        # but nothing decodes yet — count one step so a
-                        # stuck prefill cannot loop forever
-                        steps += 1
-                        continue
-                    self._collect(results)
+            for _ in range(max_steps):
+                self.step()
+                if self._drained():
                     break
-                if steps >= max_steps:
-                    break
-                t_block = self._block_len(
-                    live, min(self.decode_block, max_steps - steps))
-                steps += self._launch(live, t_block)
-                if not self.async_dispatch:
-                    self._account_one()
-                    self._collect(results)
-                    if self._drained():
-                        break
-                continue
-            # async: block k is in flight — dispatch block k+1 from the
-            # device carries BEFORE syncing block k, so the host accounting
-            # below overlaps block k+1's device compute.  A prefill chunk
-            # can ride here too: it feeds a masked-out slot, which leaves
-            # the decode carries untouched.
-            self._advance_prefill()
-            live = self._live()
-            speculated = False
-            # (no `_joinable` gate here: a batch drain has no TTFT to
-            # protect, so throughput-optimal speculation runs whenever the
-            # carries are valid — the occupancy-change drain below still
-            # bounds the cost of speculating past a finish to one block)
-            if steps < max_steps and live \
-                    and self._carry is not None \
-                    and self._carry[0] == self._live_key(live):
-                t_spec = self._block_len_ahead(
-                    live, min(self.decode_block, max_steps - steps),
-                    self._inflight[-1].t_block)
-                if t_spec > 0:
-                    steps += self._launch(live, t_spec)
-                    speculated = True
-            changed = self._account_one()
-            self._collect(results)
-            if changed and speculated:
-                # occupancy changed under the speculative block: drain it
-                # (its tokens are still oracle-exact) so the next launch
-                # sees the post-change occupancy from host state
-                self._account_one()
-                self._collect(results)
-            if not self._inflight and self._drained():
-                break
-        return results
-
-    def _run_per_token(self, max_steps: int) -> Dict[int, List[int]]:
-        results: Dict[int, List[int]] = {}
-        for _ in range(max_steps):
-            self._collect(results)      # before step()'s admit overwrites
-            self.step()
-            self._collect(results)
-            if not self.queue and all(s.req is None or s.req.done
-                                      for s in self.slots):
-                break
-        return results
+        else:
+            steps = 0
+            while steps < max_steps and (self._inflight
+                                         or not self._drained()):
+                d0, s0 = c["decode_steps"], c["syncs"]
+                self.decode_block_step(
+                    min(self.decode_block, max_steps - steps))
+                d = c["decode_steps"] - d0
+                steps += d if d or c["syncs"] != s0 else 1
+            self.flush()
+        return {uid: out for uid, (_, out, _) in self._retired.items()
+                if uid not in before}
 
 
 def build_serve_step(cfg: ArchConfig):

@@ -1,13 +1,14 @@
-"""Async double-buffered dispatch + admission policies (ISSUE 7).
+"""Double-buffered dispatch + admission policies.
 
-The engine now dispatches block k+1 from device-resident carries *before*
+The engine dispatches block k+1 from device-resident carries *before*
 syncing block k's token array (deferring host accounting by one block),
 and admission is a pluggable policy.  These tests pin the contract:
-async ≡ sync ≡ per-token oracle token-for-token — across state families,
-greedy and sampled, under randomized staggered arrivals — plus the
+async ≡ per-token oracle token-for-token — across state families, greedy
+and sampled, under randomized staggered arrivals — plus the
 occupancy-change rule, EOS inside a deferred block, ``flush()``
-semantics, the device-carry launch fast path, and the
-``AdaptiveAdmission`` policy surface.
+semantics, the device-carry launch fast path, the drain running the same
+dispatches as the serving tick, and the ``AdaptiveAdmission`` policy
+surface.
 """
 import dataclasses
 import functools
@@ -48,12 +49,11 @@ _PROMPTS = [np.array([3, 5, 7], np.int32), np.array([2, 4], np.int32),
             np.array([9, 1, 8], np.int32), np.array([6], np.int32)]
 
 
-def _drain(cfg, params, *, fused=True, async_dispatch=True, exec_cfg=None,
+def _drain(cfg, params, *, fused=True, exec_cfg=None,
            prompts=_PROMPTS, max_new=6, n_slots=2, decode_block=4,
            sampling=None, **kw):
     eng = ServeEngine(cfg, params, n_slots=n_slots, max_seq=48,
                       exec_cfg=exec_cfg, fused=fused,
-                      async_dispatch=async_dispatch,
                       decode_block=decode_block, **kw)
     for p in prompts:
         eng.submit(p, max_new=max_new, sampling=sampling)
@@ -63,15 +63,13 @@ def _drain(cfg, params, *, fused=True, async_dispatch=True, exec_cfg=None,
 
 
 # ---------------------------------------------------------------------------
-# async ≡ sync ≡ oracle across families
+# async ≡ oracle across families
 # ---------------------------------------------------------------------------
 
 def test_async_matches_sync_and_oracle_dense():
     cfg, params = _tiny()
     oracle = _drain(cfg, params, fused=False)
-    sync = _drain(cfg, params, async_dispatch=False)
-    async_ = _drain(cfg, params, async_dispatch=True)
-    assert oracle == sync == async_
+    assert _drain(cfg, params) == oracle
 
 
 def test_async_matches_sync_planned_sparse():
@@ -81,31 +79,27 @@ def test_async_matches_sync_planned_sparse():
                                      activation_threshold=0.1))
     ec = decode_exec_config(sp_cfg, n_slots=2, params=params)
     assert ec.plan is not None and ec.plan.entries
-    sync = _drain(cfg, params, exec_cfg=ec, async_dispatch=False)
-    async_ = _drain(cfg, params, exec_cfg=ec, async_dispatch=True)
-    assert sync == async_ == _drain(cfg, params, exec_cfg=ec, fused=False)
+    async_ = _drain(cfg, params, exec_cfg=ec)
+    assert async_ == _drain(cfg, params, exec_cfg=ec, fused=False)
 
 
 @pytest.mark.slow
 def test_async_matches_sync_moe():
-    cfg = get_smoke_config("deepseek-moe-16b")
-    params = model_lib.init_params(cfg, jax.random.PRNGKey(0),
-                                   dtype=jnp.float32)
-    sync = _drain(cfg, params, async_dispatch=False, max_new=4)
-    async_ = _drain(cfg, params, async_dispatch=True, max_new=4)
-    assert sync == async_ == _drain(cfg, params, fused=False, max_new=4)
+    cfg, params, _ = _moe_setup()
+    async_ = _drain(cfg, params, max_new=4)
+    assert async_ == _drain(cfg, params, fused=False, max_new=4)
 
 
 def test_async_sampled_streams_match_sync():
     """Sampling is position-keyed, so deferred accounting cannot perturb
-    it: async and sync sampled streams are identical per seed."""
+    it: async and per-token oracle sampled streams are identical per
+    seed."""
     cfg, params = _tiny()
     sp = SamplingParams(temperature=0.9, top_k=12, seed=11)
-    sync = _drain(cfg, params, async_dispatch=False, sampling=sp)
-    async_ = _drain(cfg, params, async_dispatch=True, sampling=sp)
-    assert sync == async_
+    async_ = _drain(cfg, params, sampling=sp)
+    assert async_ == _drain(cfg, params, fused=False, sampling=sp)
     # and reproducible: a second async run emits the same streams
-    assert async_ == _drain(cfg, params, async_dispatch=True, sampling=sp)
+    assert async_ == _drain(cfg, params, sampling=sp)
 
 
 # ---------------------------------------------------------------------------
@@ -249,7 +243,7 @@ def test_occupancy_change_returns_without_draining():
 def test_eos_in_deferred_block():
     """EOS fires on device inside a block whose host accounting is
     deferred: the stream still truncates at (and including) the EOS
-    token, exactly like the sync engine."""
+    token, exactly like the per-token oracle."""
     cfg, params = _tiny()
     prompt = _prompt(np.random.default_rng(5), 7)
     eng = ServeEngine(cfg, params, n_slots=1, max_seq=64)
@@ -258,12 +252,11 @@ def test_eos_in_deferred_block():
     eos = ref[4]
     cut = ref.index(eos) + 1
     streams = {}
-    for async_dispatch in (True, False):
+    for fused in (True, False):
         e = ServeEngine(cfg, params, n_slots=2, max_seq=64,
-                        eos_id=int(eos), decode_block=4,
-                        async_dispatch=async_dispatch)
+                        eos_id=int(eos), decode_block=4, fused=fused)
         uu = e.submit(prompt, max_new=12)
-        streams[async_dispatch] = e.run_until_drained()[uu]
+        streams[fused] = e.run_until_drained()[uu]
         assert all(s.req is None or s.req.done for s in e.slots)
     assert streams[True] == ref[:cut] == streams[False]
 
@@ -273,7 +266,7 @@ def test_decode_block_step_defers_by_one_block():
     synced in its own tick (first-token urgency — TTFT never pays the
     deferral); after that the engine double-buffers: the next tick
     launches and returns nothing, ``flush()`` returns the deferred tail.
-    The total equals the sync engine's stream."""
+    The total equals the per-token oracle's stream."""
     cfg, params = _tiny()
     eng = ServeEngine(cfg, params, n_slots=1, max_seq=48, decode_block=4)
     u = eng.submit(np.array([3, 5, 7], np.int32), max_new=8)
@@ -285,27 +278,84 @@ def test_decode_block_step_defers_by_one_block():
     toks = first[u] + tail.get(u, [])
     assert not eng._inflight
 
-    sync = ServeEngine(cfg, params, n_slots=1, max_seq=48, decode_block=4,
-                       async_dispatch=False)
-    us = sync.submit(np.array([3, 5, 7], np.int32), max_new=8)
-    sync_toks = []
-    for _ in range(2):
-        sync_toks.extend(sync.decode_block_step().get(us, []))
-    assert toks == sync_toks
+    assert [toks] == _oracle_streams([(np.array([3, 5, 7], np.int32), 8)])
 
 
-def test_sync_flag_keeps_one_block_per_call():
-    """``async_dispatch=False`` restores the classic contract: every
-    ``decode_block_step`` call returns the block it dispatched and leaves
-    nothing in flight."""
-    cfg, params = _tiny()
-    eng = ServeEngine(cfg, params, n_slots=1, max_seq=48, decode_block=4,
-                      async_dispatch=False)
-    u = eng.submit(np.array([3, 5, 7], np.int32), max_new=8)
-    for _ in range(2):
-        out = eng.decode_block_step()
-        assert len(out.get(u, [])) == 4
-        assert not eng._inflight
+def _sparse_setup():
+    from repro.core.sparsity import prune_stacked_magnitude
+    cfg = dataclasses.replace(_tiny_cfg(),
+                              sparsity=SparsityConfig(weight_sparsity=0.5))
+    params = jax.tree.map(
+        lambda x: (prune_stacked_magnitude(x, 0.5, block=(16, 16))
+                   .astype(x.dtype)
+                   if x.ndim >= 2 and min(x.shape[-2:]) >= 16 else x),
+        _tiny()[1])
+    ec = decode_exec_config(cfg, n_slots=2, params=params)
+    assert ec.plan is not None and ec.plan.entries
+    return cfg, params, ec
+
+
+def _moe_setup():
+    cfg = get_smoke_config("deepseek-moe-16b")
+    return cfg, model_lib.init_params(cfg, jax.random.PRNGKey(0),
+                                      dtype=jnp.float32), None
+
+
+_DRAIN_SETUPS = {"dense": lambda: (*_tiny(), None), "weight": _sparse_setup,
+                 "moe": _moe_setup}
+
+
+@pytest.mark.parametrize("setup", list(_DRAIN_SETUPS))
+def test_drain_dispatches_like_the_tick(setup):
+    """``run_until_drained`` is serving ticks until drained: on a schedule
+    where a queued request waits for the slot a short request frees, the
+    drain dispatches the same prefill segments and decode blocks, in the
+    same order, as ``decode_block_step`` driven until ``health()`` shows
+    nothing queued, live or in flight, and both emit the oracle's
+    streams."""
+    cfg, params, ec = _DRAIN_SETUPS[setup]()
+    rng = np.random.default_rng(7)
+    reqs = [(_prompt(rng, 3, cfg.vocab), 3),      # short: frees its slot
+            (_prompt(rng, 6, cfg.vocab), 10),
+            (_prompt(rng, 9, cfg.vocab), 6)]      # waits for a slot
+
+    def run(drain):
+        eng = ServeEngine(cfg, params, n_slots=2, max_seq=48, exec_cfg=ec,
+                          prefill_chunk=4, decode_block=4)
+        seen, prefill, decode_many = [], eng._prefill, eng._decode_many
+
+        def rec_prefill(p, s, toks, valid, *rest):
+            seen.append(("prefill", len(toks), int(np.sum(valid))))
+            return prefill(p, s, toks, valid, *rest)
+
+        def rec_decode(*args):
+            seen.append(("decode", int(args[9]),
+                         tuple(np.asarray(args[4]).tolist())))
+            return decode_many(*args)
+
+        eng._prefill, eng._decode_many = rec_prefill, rec_decode
+        uids = [eng.submit(p, max_new=mn) for p, mn in reqs]
+        if drain:
+            out = eng.run_until_drained()
+        else:
+            for _ in range(200):
+                h = eng.health()
+                if not (h["queue_depth"] or h["decoding"]
+                        or h["prefilling"] or h["inflight_blocks"]):
+                    break
+                eng.decode_block_step()
+            eng.flush()
+            out = eng.results()
+        return seen, [out[u] for u in uids]
+
+    drained, ticked = run(True), run(False)
+    assert drained[0] == ticked[0]
+    assert drained[1] == ticked[1]
+    oracle = ServeEngine(cfg, params, n_slots=2, max_seq=48, exec_cfg=ec,
+                         fused=False)
+    ouids = [oracle.submit(p, max_new=mn) for p, mn in reqs]
+    ores = oracle.run_until_drained()
+    assert drained[1] == [ores[u] for u in ouids]
 
 
 def test_flush_is_idempotent_and_credits_requests():

@@ -107,7 +107,7 @@ def test_cancel_queued_request():
 
 def test_cancel_mid_prefill_and_mid_decode():
     cfg, params = _tiny()
-    eng = _engine(cfg, params, prefill_chunk=2, async_dispatch=True)
+    eng = _engine(cfg, params, prefill_chunk=2)
     a = eng.submit(list(range(1, 9)), max_new=40)   # 8-token prompt, 4 chunks
     b = eng.submit([4, 5, 6], max_new=8)
     eng.decode_block_step()
@@ -126,7 +126,7 @@ def test_cancel_mid_prefill_and_mid_decode():
     assert eng.results()[b] == orc.run_until_drained()[ob]
 
     # mid-decode: let the request stream a few tokens first
-    eng2 = _engine(cfg, params, async_dispatch=True)
+    eng2 = _engine(cfg, params)
     c = eng2.submit([1, 2, 3], max_new=40)
     for _ in range(4):
         eng2.decode_block_step()
@@ -146,7 +146,7 @@ def test_cancel_mid_speculation_never_leaks_into_successor():
     same slot, and require the successor's stream to be oracle-exact (no
     token from the cancelled request's in-flight block leaks)."""
     cfg, params = _tiny()
-    eng = _engine(cfg, params, n_slots=1, async_dispatch=True)
+    eng = _engine(cfg, params, n_slots=1)
     a = eng.submit([1, 2, 3], max_new=40)
     b = eng.submit([7, 8], max_new=6)               # waits for the slot
     for _ in range(3):
@@ -166,7 +166,7 @@ def test_cancel_mid_speculation_never_leaks_into_successor():
 
 def test_cancel_mid_speculation_planned_tiers():
     cfg, params, ec = _planned()
-    eng = _engine(cfg, params, n_slots=1, exec_cfg=ec, async_dispatch=True,
+    eng = _engine(cfg, params, n_slots=1, exec_cfg=ec,
                   plan_tiers=(0.0, 0.5), speculate_k=3)
     a = eng.submit([1, 2, 3], max_new=40)
     b = eng.submit([7, 8, 9], max_new=8)
@@ -191,7 +191,7 @@ def test_cancel_mid_speculation_planned_tiers():
 def test_deadline_expiry_queued_and_decoding():
     cfg, params = _tiny()
     clk = VirtualClock()
-    eng = _engine(cfg, params, n_slots=1, clock=clk, async_dispatch=True)
+    eng = _engine(cfg, params, n_slots=1, clock=clk)
     a = eng.submit([1, 2, 3], max_new=40, deadline=10.0)   # will be decoding
     b = eng.submit([4, 5], max_new=4, deadline=10.0)       # expires queued
     c = eng.submit([6, 7], max_new=4)                      # no deadline
@@ -226,9 +226,10 @@ def test_deadline_pressure_demotes_to_cheaper_tier():
     cfg, params, ec = _planned()
     clk = VirtualClock()
     eng = _engine(cfg, params, exec_cfg=ec, clock=clk,
-                  plan_tiers=(0.0, 0.5), async_dispatch=False)
+                  plan_tiers=(0.0, 0.5))
     a = eng.submit([1, 2, 3], max_new=30, deadline=5.0)
     eng.decode_block_step()                 # admit + start decoding
+    eng.flush()
     eng._tok_ema = 1.0                      # 1 s/token measured service rate
     eng._maybe_demote()                     # 30 tokens needed, 5 s budget
     req = next(s.req for s in eng.slots if s.req is not None
@@ -260,11 +261,12 @@ def test_no_demotion_without_deadline_or_single_tier():
 def test_nan_quarantine_isolates_one_slot():
     from repro.serve.faults import poison_slot_state
     cfg, params = _tiny()
-    eng = _engine(cfg, params, async_dispatch=False)
+    eng = _engine(cfg, params)
     a = eng.submit([1, 2, 3, 4], max_new=40)
     b = eng.submit([5, 6, 7], max_new=8)
     for _ in range(4):                      # both prefilled and decoding
         eng.decode_block_step()
+    eng.flush()
     slot_a = next(i for i, s in enumerate(eng.slots)
                   if s.req is not None and s.req.uid == a)
     poison_slot_state(eng, slot_a)
@@ -335,8 +337,8 @@ def test_max_queue_validation():
 
 def test_flush_safe_and_idempotent_on_fresh_engine():
     cfg, params = _tiny()
-    for async_dispatch in (False, True):
-        eng = _engine(cfg, params, async_dispatch=async_dispatch)
+    for fused in (False, True):
+        eng = _engine(cfg, params, fused=fused)
         eng.flush()                         # never dispatched: must be a no-op
         eng.flush()
         assert eng._inflight == [] and eng.results() == {}
@@ -366,7 +368,7 @@ def test_activation_densities_zero_sample_guard():
 
 def test_health_snapshot():
     cfg, params = _tiny()
-    eng = _engine(cfg, params, max_queue=8, async_dispatch=True)
+    eng = _engine(cfg, params, max_queue=8)
     h0 = eng.health()
     assert h0["queue_depth"] == 0 and h0["inflight_blocks"] == 0
     assert h0["max_queue"] == 8 and h0["requests"] == {}
@@ -497,7 +499,7 @@ def _run_chaos(seed, cfg, params, *, exec_cfg=None, kinds=_TARGETED,
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_chaos_dense_async(seed):
     cfg, params = _tiny()
-    _run_chaos(seed, cfg, params, async_dispatch=True)
+    _run_chaos(seed, cfg, params)
 
 
 @pytest.mark.slow
@@ -505,7 +507,7 @@ def test_chaos_dense_async(seed):
 @given(seed=st.integers(min_value=0, max_value=10**6))
 def test_chaos_planned_speculative(seed):
     cfg, params, ec = _planned()
-    eng = _run_chaos(seed, cfg, params, exec_cfg=ec, async_dispatch=True,
+    eng = _run_chaos(seed, cfg, params, exec_cfg=ec,
                      plan_tiers=(0.0, 0.5), speculate_k=3)
     assert eng._spec_windowed               # speculation was actually on
 
@@ -519,6 +521,6 @@ def test_chaos_two_sided_with_recalibration(seed):
     poison — chaos here sticks to cancel/delay/recalibrate faults and
     additionally forces a mid-traffic recalibration."""
     cfg, params, ec = _planned(two_sided=True)
-    eng = _run_chaos(seed, cfg, params, exec_cfg=ec, async_dispatch=True,
+    eng = _run_chaos(seed, cfg, params, exec_cfg=ec,
                      kinds=("cancel",), with_recal=True)
     assert eng._stats is not None

@@ -249,19 +249,6 @@ def test_popcounts_and_recalibrate_after_fused_run(cfg_and_params):
     assert again[u2] == first[u1]
 
 
-def test_donated_state_matches_undonated(cfg_and_params):
-    """donate_state only changes buffer aliasing, never tokens."""
-    cfg, params = cfg_and_params
-    outs = []
-    for donate in (True, False):
-        eng = ServeEngine(cfg, params, n_slots=2, max_seq=32, fused=True,
-                          donate_state=donate)
-        for p in _PROMPTS[:2]:
-            eng.submit(p, max_new=4)
-        outs.append(eng.run_until_drained())
-    assert outs[0] == outs[1]
-
-
 def test_queue_is_constant_time_deque(cfg_and_params):
     """The request queue must be a deque (O(1) admits under deep queues)."""
     import collections

@@ -71,7 +71,7 @@ class _Recorder:
 def test_spans_name_nest_and_carry_the_dispatch(monkeypatch):
     rec = _Recorder()
     monkeypatch.setattr(engine_mod, "TraceAnnotation", rec)
-    eng = _engine(prefill_chunk=4, async_dispatch=True)
+    eng = _engine(prefill_chunk=4)
     uid = eng.submit(np.arange(1, 12), max_new=9)    # feed 10: 4 + 4 + 2
     _drive(eng)
     assert eng.status(uid) == "done"
@@ -101,12 +101,11 @@ def test_spans_name_nest_and_carry_the_dispatch(monkeypatch):
     assert len(by("serve.account")) == len(syncs)
 
 
-@pytest.mark.parametrize("async_dispatch,chunk", [(True, 4), (False, 4),
-                                                  (True, None)])
-def test_counters_equal_the_dispatched_work(async_dispatch, chunk):
+@pytest.mark.parametrize("chunk", [4, None])
+def test_counters_equal_the_dispatched_work(chunk):
     """Counter deltas against the executables' own arguments: valid and
     padded prefill steps, scanned decode steps, one sync per block."""
-    eng = _engine(prefill_chunk=chunk, async_dispatch=async_dispatch)
+    eng = _engine(prefill_chunk=chunk)
     eng.submit(np.arange(1, 3), max_new=2)           # warm one request
     _drive(eng)
     before = dict(eng.health()["counters"])

@@ -428,8 +428,7 @@ def test_verify_blocks_drain_on_occupancy_change(tier_setup):
         assert not eng._inflight               # nothing stranded
         return eng, out
 
-    eng, out = run(plan_tiers=(0.0, 0.5), speculate_k=3,
-                   async_dispatch=True)
+    eng, out = run(plan_tiers=(0.0, 0.5), speculate_k=3)
     _, oracle = run(fused=False)
     assert out == oracle
     assert eng.spec_stats["verify_blocks"] > 0
